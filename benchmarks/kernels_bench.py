@@ -33,7 +33,7 @@ def bench_flash() -> Dict[str, float]:
         lambda *a: kref.flash_attention_ref(*a, causal=True)), q, k, v)
     t_pal, o_pal = _time(jax.jit(
         lambda *a: ops.flash_attention(*a, causal=True,
-                                       backend="pallas")), q, k, v)
+                                       backend="interpret")), q, k, v)
     err = float(jnp.abs(o_ref - o_pal).max())
     return {"kernel": "flash_attention", "xla_us": t_ref * 1e6,
             "pallas_interp_us": t_pal * 1e6, "max_err": err}
@@ -52,7 +52,7 @@ def bench_ssd() -> Dict[str, float]:
         lambda *a: ops.ssd_scan(*a, chunk=128, backend="xla")),
         x, dt, A, Bm, Cm)
     t_pal, (y_pal, _) = _time(jax.jit(
-        lambda *a: ops.ssd_scan(*a, chunk=128, backend="pallas")),
+        lambda *a: ops.ssd_scan(*a, chunk=128, backend="interpret")),
         x, dt, A, Bm, Cm)
     err = float(jnp.abs(y_ref - y_pal).max())
     return {"kernel": "ssd_scan", "xla_us": t_ref * 1e6,
@@ -65,7 +65,7 @@ def bench_gmm() -> Dict[str, float]:
     w = jax.random.normal(jax.random.PRNGKey(1), (e, d, f))
     t_ref, o_ref = _time(jax.jit(kref.moe_gmm_ref), xb, w)
     t_pal, o_pal = _time(jax.jit(
-        lambda *a: ops.moe_gmm(*a, backend="pallas")), xb, w)
+        lambda *a: ops.moe_gmm(*a, backend="interpret")), xb, w)
     err = float(jnp.abs(o_ref - o_pal).max() / jnp.abs(o_ref).max())
     return {"kernel": "moe_gmm", "xla_us": t_ref * 1e6,
             "pallas_interp_us": t_pal * 1e6, "max_err": err}

@@ -20,23 +20,36 @@ SIZE = 1 << 14       # elements per rank
 REPEAT = 20
 
 
-def bench(op: str, backend: str) -> Dict[str, float]:
-    stats = {}
+OPS = ("all_gather", "reduce_scatter", "all_reduce", "all_to_all")
+
+
+def backends(op: str) -> tuple:
+    """(LCX backend, native) pair compared for ``op``."""
+    return ("pairwise", "native") if op == "all_to_all" \
+        else ("ring", "native")
+
+
+def collective_body(op: str, backend: str, stats: Dict = None):
+    """Per-rank body over axis ``x`` (under vmap or shard_map); the
+    device's transfer statistics land in ``stats``."""
+    fn = {"all_gather": lcx.all_gather,
+          "reduce_scatter": lcx.reduce_scatter,
+          "all_reduce": lcx.all_reduce, "all_to_all": lcx.all_to_all}[op]
 
     def body(x):
         lcx.init()
         dev = lcx.Device(axis="x")
-        if op == "all_gather":
-            out = lcx.all_gather(x, device=dev, backend=backend)
-        elif op == "reduce_scatter":
-            out = lcx.reduce_scatter(x, device=dev, backend=backend)
-        elif op == "all_reduce":
-            out = lcx.all_reduce(x, device=dev, backend=backend)
-        else:
-            out = lcx.all_to_all(x, device=dev, backend=backend)
-        stats.update(dev.stats)
+        out = fn(x, device=dev, backend=backend)
+        if stats is not None:
+            stats.update(dev.stats)
         return out
 
+    return body
+
+
+def bench(op: str, backend: str) -> Dict[str, float]:
+    stats = {}
+    body = collective_body(op, backend, stats)
     xs = jnp.arange(float(N * SIZE)).reshape(N, SIZE)
     fn = jax.jit(jax.vmap(body, axis_name="x"))
     out = fn(xs)
@@ -55,11 +68,8 @@ def main(out_csv: str = None) -> List[Dict[str, float]]:
     rows = []
     print(f"{'op':16s} {'backend':9s} {'us/call':>10s} "
           f"{'p2p':>5s} {'KiB/rank':>9s}")
-    for op in ("all_gather", "reduce_scatter", "all_reduce",
-               "all_to_all"):
-        backends = ("pairwise", "native") if op == "all_to_all" \
-            else ("ring", "native")
-        for backend in backends:
+    for op in OPS:
+        for backend in backends(op):
             r = bench(op, backend)
             rows.append(r)
             print(f"{r['op']:16s} {r['backend']:9s} "

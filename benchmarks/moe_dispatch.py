@@ -2,9 +2,11 @@
 asynchronous a2a pattern, LCX-routed).
 
 Sweeps token counts through the sort-based capacity dispatch + EP
-all-to-all (2 fake-device subprocess like the ping-pong) and reports
-tokens/s plus drop rate at the configured capacity factor.  Single-pod
-the dominant MoE cost is exactly this path (EXPERIMENTS.md §Roofline).
+all-to-all over a ``model`` axis of every device in the process, and
+reports tokens/s.  With ``JAX_PLATFORMS=cpu`` the script decides, before
+it imports JAX, to re-run itself in a child on 2 fake CPU devices
+(``JAX_PLATFORMS=cpu`` set explicitly, as in ``pingpong.py``); every row
+names the platform and device count it ran on.
 """
 from __future__ import annotations
 
@@ -16,7 +18,7 @@ import time
 from typing import Dict, List
 
 TOKENS = (256, 1024, 4096)
-N_RANKS = 2
+FAKE_DEVICES = 2     # CPU child only; on chips every device is a rank
 
 
 def _run_inproc(n_tokens: int, a2a_backend: str) -> Dict[str, float]:
@@ -28,7 +30,8 @@ def _run_inproc(n_tokens: int, a2a_backend: str) -> Dict[str, float]:
     from repro.parallel.sharding import use_mesh, param_shardings
     from repro.compat import make_mesh
 
-    mesh = make_mesh((1, N_RANKS), ("data", "model"))
+    devices = jax.devices()
+    mesh = make_mesh((1, len(devices)), ("data", "model"))
     cfg = ModelConfig(name="bench", family="moe", n_layers=1, d_model=128,
                       n_heads=2, n_kv_heads=2, d_ff=256, vocab=64,
                       n_experts=8, n_experts_per_tok=2, moe_d_ff=256,
@@ -52,40 +55,49 @@ def _run_inproc(n_tokens: int, a2a_backend: str) -> Dict[str, float]:
         jax.block_until_ready(out)
         dt = (time.perf_counter() - t0) / 10
     return {"tokens": n_tokens, "a2a": a2a_backend,
+            "platform": devices[0].platform, "devices": len(devices),
             "us_per_call": dt * 1e6, "tokens_per_s": n_tokens / dt}
 
 
-def _child() -> None:
-    rows = []
-    for t in TOKENS:
-        for backend in ("native", "pairwise"):
-            rows.append(_run_inproc(t, backend))
-    print("MOEDISPATCH_JSON=" + json.dumps(rows))
+def _rows() -> List[Dict[str, float]]:
+    import jax
+    if len(jax.devices()) < 2:
+        raise RuntimeError(
+            f"EP dispatch needs at least 2 devices, found {jax.devices()}; "
+            f"set JAX_PLATFORMS=cpu to run on fake CPU devices")
+    return [_run_inproc(t, backend)
+            for t in TOKENS for backend in ("native", "pairwise")]
+
+
+def _rows_in_cpu_child() -> List[Dict[str, float]]:
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
+                        f" --xla_force_host_platform_device_count="
+                        f"{FAKE_DEVICES}")
+    out = subprocess.run([sys.executable, os.path.abspath(__file__),
+                          "--child"],
+                         capture_output=True, text=True, env=env,
+                         timeout=1200)
+    if out.returncode != 0:
+        raise RuntimeError(out.stderr[-2000:])
+    line = [l for l in out.stdout.splitlines()
+            if l.startswith("MOEDISPATCH_JSON=")][0]
+    return json.loads(line[len("MOEDISPATCH_JSON="):])
 
 
 def main(out_csv: str = None) -> List[Dict[str, float]]:
-    import jax
-    if len(jax.devices()) >= N_RANKS:
-        rows = []
-        for t in TOKENS:
-            for backend in ("native", "pairwise"):
-                rows.append(_run_inproc(t, backend))
+    # decided before JAX is imported: a parent that touched JAX would
+    # hold the chip its child needs
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
+        rows = _rows_in_cpu_child()
     else:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                            " --xla_force_host_platform_device_count=2")
-        env["MOEDISPATCH_CHILD"] = "1"
-        out = subprocess.run([sys.executable, os.path.abspath(__file__)],
-                             capture_output=True, text=True, env=env,
-                             timeout=1200)
-        if out.returncode != 0:
-            raise RuntimeError(out.stderr[-2000:])
-        line = [l for l in out.stdout.splitlines()
-                if l.startswith("MOEDISPATCH_JSON=")][0]
-        rows = json.loads(line[len("MOEDISPATCH_JSON="):])
-    print(f"{'tokens':>7s} {'a2a':9s} {'us/call':>10s} {'Mtok/s':>8s}")
+        rows = _rows()
+    print(f"{'tokens':>7s} {'a2a':9s} {'platform':>8s} {'devs':>4s} "
+          f"{'us/call':>10s} {'Mtok/s':>8s}")
     for r in rows:
-        print(f"{r['tokens']:7d} {r['a2a']:9s} {r['us_per_call']:10.1f} "
+        print(f"{r['tokens']:7d} {r['a2a']:9s} {r['platform']:>8s} "
+              f"{r['devices']:4d} {r['us_per_call']:10.1f} "
               f"{r['tokens_per_s']/1e6:8.3f}")
     if out_csv:
         import csv
@@ -97,7 +109,7 @@ def main(out_csv: str = None) -> List[Dict[str, float]]:
 
 
 if __name__ == "__main__":
-    if os.environ.get("MOEDISPATCH_CHILD"):
-        _child()
+    if sys.argv[1:2] == ["--child"]:
+        print("MOEDISPATCH_JSON=" + json.dumps(_rows()))
     else:
         main()

@@ -32,6 +32,8 @@ def main() -> None:
                    help="trim the lane sweep for CI")
     args = p.parse_args()
 
+    from repro.launch.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     os.makedirs("results", exist_ok=True)
 
     print("=" * 72)

@@ -33,7 +33,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.launch.mesh import (HBM_BW, ICI_AXIS_BW, PEAK_FLOPS_BF16)
+from repro.launch.mesh import DRYRUN_DEVICE_KIND, peaks
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1,
@@ -235,9 +235,11 @@ def analyze_compiled(compiled: Any, *, arch: str, shape: str, mesh_name: str,
     by_kind: Dict[str, float] = dict(totals.coll_by_kind)
     n_coll = int(totals.n_coll)
 
-    compute_s = flops / PEAK_FLOPS_BF16
-    memory_s = nbytes / HBM_BW
-    collective_s = wire / ICI_AXIS_BW
+    peak = peaks(DRYRUN_DEVICE_KIND)
+    compute_s = flops / peak["flops_bf16"]
+    memory_s = nbytes / peak["hbm_bw"]
+    # a ring uses both directions of an axis
+    collective_s = wire / (2 * peak["ici_link_bw"])
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     bottleneck = max(terms, key=terms.get)

@@ -1,11 +1,8 @@
-"""JAX version compatibility shims.
+"""Mesh and shard_map helpers shared by every module that builds a mesh
+or a manual region.
 
-The repo targets the newest JAX API surface (``jax.shard_map``,
-``jax.sharding.AxisType``, ``lax.axis_size``, positional
-``AbstractMesh(shape, names)``); the pinned toolchain may carry an older
-release where those spell differently.  Every module that touches one of
-the moving APIs goes through this file so version drift is absorbed in
-exactly one place.
+Every caller goes through these helpers, so the spelling of the mesh
+and shard_map APIs of the installed JAX (0.9) lives in one place.
 """
 from __future__ import annotations
 
@@ -13,78 +10,36 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 from jax import lax
+from jax.sharding import AbstractMesh, AxisType
 
 __all__ = ["AxisType", "abstract_mesh", "axis_size", "make_mesh",
            "shard_map"]
 
-# Partitionable threefry makes sharded RNG output independent of the
-# device layout, so sharded param init bit-matches single-device init.
-# Newer JAX defaults this on; the pinned release defaults it off, which
-# silently diverges multi-host init from the eager reference.
-if hasattr(jax.config, "jax_threefry_partitionable") \
-        and not jax.config.jax_threefry_partitionable:
-    jax.config.update("jax_threefry_partitionable", True)
-
-
-try:  # JAX >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    class AxisType:  # type: ignore[no-redef]
-        """Placeholder for jax.sharding.AxisType on older JAX (where all
-        mesh axes behave like ``Auto``)."""
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
 
 def axis_size(axis: str) -> int:
-    """Size of a bound mesh/vmap axis, from inside shard_map/vmap.
-
-    ``lax.axis_size`` only exists on newer JAX; ``psum`` of a unit
-    constant folds to the same number everywhere.
-    """
-    if hasattr(lax, "axis_size"):
-        return int(lax.axis_size(axis))
-    return int(lax.psum(1, axis))
+    """Size of a bound mesh/vmap axis, from inside shard_map/vmap."""
+    return int(lax.axis_size(axis))
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str],
               axis_types: Optional[Tuple[Any, ...]] = None):
-    """``jax.make_mesh`` across the axis_types signature change."""
-    try:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(axis_types or
-                                         (AxisType.Auto,) * len(axes)))
-    except TypeError:  # old signature: no axis_types kwarg
-        return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with every axis ``Auto`` unless told otherwise."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(axis_types or
+                                     (AxisType.Auto,) * len(axes)))
 
 
 def abstract_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """``jax.sharding.AbstractMesh`` across its signature change:
-    new JAX takes ``(shape, names)``; old JAX takes one tuple of
-    ``(name, size)`` pairs."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    """A device-free mesh of ``shape`` over ``axes``."""
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def shard_map(f, mesh, in_specs, out_specs, check: bool = False,
               axis_names: Optional[set] = None):
-    """``jax.shard_map`` / ``jax.experimental.shard_map`` with the
-    ``check_vma``/``check_rep`` rename and the ``axis_names``/``auto``
-    partial-manual spelling absorbed."""
-    if hasattr(jax, "shard_map"):
-        kw: Dict[str, Any] = {"check_vma": check}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    kw = {"check_rep": check}
+    """``jax.shard_map``; ``check`` is its ``check_vma`` and
+    ``axis_names`` selects a partial-manual region."""
+    kw: Dict[str, Any] = {"check_vma": check}
     if axis_names is not None:
-        # old spelling: list the *auto* (non-manual) axes instead
-        kw["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+        kw["axis_names"] = axis_names
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)

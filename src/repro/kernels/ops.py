@@ -1,10 +1,13 @@
 """Public kernel entry points with backend selection.
 
 ``backend`` resolution per call:
-- ``"pallas"``  — compiled Pallas (TPU) or interpret mode on CPU;
-- ``"xla"``     — the ref.py oracle (pure jnp, what the dry-run lowers);
-- ``None``      — auto: compiled Pallas on TPU, ``xla`` elsewhere (the
-  dry-run's CPU placeholder devices cannot compile Mosaic kernels).
+- ``"pallas"``    — the compiled Mosaic kernel; needs the TPU backend
+  and raises anywhere else;
+- ``"interpret"`` — the same kernel in Pallas interpret mode, which the
+  CPU tests ask for by name;
+- ``"xla"``       — the ref.py oracle (pure jnp, what the dry-run lowers);
+- ``None``        — by the backend JAX runs on: ``pallas`` on TPU,
+  ``xla`` elsewhere (XLA's CPU backend cannot compile Mosaic kernels).
 
 ``model_kernels(cfg)`` builds the kernels dict consumed by
 `repro.models` (signatures match ``attn_apply``/``ssm_apply`` hooks).
@@ -21,6 +24,8 @@ from .flash_attention import flash_attention as _flash_pallas
 from .moe_gmm import moe_gmm as _gmm_pallas
 from .ring_allgather import ring_all_gather as _ring_pallas
 
+BACKENDS = ("pallas", "interpret", "xla")
+
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
@@ -29,6 +34,14 @@ def on_tpu() -> bool:
 def _resolve(backend: Optional[str]) -> str:
     if backend is None:
         return "pallas" if on_tpu() else "xla"
+    if backend not in BACKENDS:
+        raise ValueError(f"unknown kernel backend {backend!r}; "
+                         f"expected one of {BACKENDS}")
+    if backend == "pallas" and not on_tpu():
+        raise RuntimeError(
+            f"backend='pallas' compiles Mosaic kernels for the TPU, but "
+            f"JAX runs on {jax.default_backend()!r}; ask for "
+            f"backend='interpret' to run the kernel in interpret mode")
     return backend
 
 
@@ -47,7 +60,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          scale=(q.shape[-1] ** -0.5 if scale is None
                                 else scale),
                          block_q=block_q, block_k=block_k,
-                         interpret=not on_tpu())
+                         interpret=(be == "interpret"))
 
 
 # ---------------------------------------------------------------------------
@@ -77,8 +90,8 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
 
     y, hf = ssd_scan_chunked(
         chunked(x), chunked(dt[..., None]),
-        A.astype(jnp.float32)[:, None], chunked(Bm), chunked(Cm),
-        interpret=not on_tpu())
+        A.astype(jnp.float32), chunked(Bm), chunked(Cm),
+        interpret=(be == "interpret"))
     y = jnp.moveaxis(y.reshape(b, h, s, p), 1, 2)
     return y, hf
 
@@ -91,7 +104,7 @@ def moe_gmm(xb: jax.Array, w: jax.Array, *,
     be = _resolve(backend)
     if be == "xla":
         return _ref.moe_gmm_ref(xb, w)
-    return _gmm_pallas(xb, w, interpret=not on_tpu())
+    return _gmm_pallas(xb, w, interpret=(be == "interpret"))
 
 
 # ---------------------------------------------------------------------------
@@ -103,7 +116,7 @@ def ring_all_gather(x: jax.Array, axis: str, *, axis_size: int,
     if be == "xla":
         return _ref.ring_allgather_ref(x, axis)
     return _ring_pallas(x, axis, axis_size=axis_size,
-                        interpret=not on_tpu())
+                        interpret=(be == "interpret"))
 
 
 # ---------------------------------------------------------------------------
@@ -114,9 +127,13 @@ def model_kernels(cfg: Any, backend: Optional[str] = None
     """Kernels dict for `repro.models` hooks.
 
     - flash_attention hook signature: (q,k,v [B,S,H,D], causal, scale)
-      -> [B,S,Hq,Dv]   (model layout: seq-major)
+      -> [B,S,Hq,Dv]   (model layout: seq-major).  Left out for
+      sliding-window configs: the kernel has no window, and the model's
+      own attention applies it.
     - ssd_scan hook signature: (x,dt,A,B,C, chunk) -> (y, h_final)
     """
+    backend = _resolve(backend)
+
     def attn_hook(q, k, v, *, causal, scale):
         qT = jnp.swapaxes(q, 1, 2)
         kT = jnp.swapaxes(k, 1, 2)
@@ -129,4 +146,7 @@ def model_kernels(cfg: Any, backend: Optional[str] = None
     def ssd_hook(x, dt, A, Bm, Cm, *, chunk):
         return ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, backend=backend)
 
-    return {"flash_attention": attn_hook, "ssd_scan": ssd_hook}
+    kernels: Dict[str, Any] = {"ssd_scan": ssd_hook}
+    if cfg.sliding_window is None:
+        kernels["flash_attention"] = attn_hook
+    return kernels

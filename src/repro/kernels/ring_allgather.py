@@ -15,21 +15,12 @@ real hardware, where the DMA read engine snapshots the source at
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-
-def tpu_interpret_available() -> bool:
-    """True when this JAX release carries the TPU interpret machinery the
-    ring kernel needs on CPU (``InterpretParams`` + ``sync_copy``).
-    Older pins (e.g. 0.4.x) lack both; callers should skip/fallback."""
-    return (hasattr(pltpu, "InterpretParams")
-            and hasattr(pltpu, "sync_copy"))
 
 
 def _ring_kernel(x_ref, o_ref, send_sem, recv_sem, *, axis: str, n: int):
@@ -51,29 +42,20 @@ def _ring_kernel(x_ref, o_ref, send_sem, recv_sem, *, axis: str, n: int):
 
 
 def ring_all_gather(x: jax.Array, axis: str, *, axis_size: int,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """Under shard_map: x [1, ...] (this device's shard, leading axis 1)
-    -> [axis_size, ...] (all shards).  TPU-only at scale; interpret mode
-    simulates the DMAs on CPU."""
+    -> [axis_size, ...] (all shards).  Compiled for the TPU;
+    ``interpret=True`` simulates the DMAs on CPU."""
     n = axis_size
-    if interpret and not tpu_interpret_available():
-        raise NotImplementedError(
-            "ring_all_gather interpret mode needs pltpu.InterpretParams "
-            "and pltpu.sync_copy, absent from the pinned JAX release — "
-            "run on real TPU or upgrade JAX")
     kernel = functools.partial(_ring_kernel, axis=axis, n=n)
-    ip = pltpu.InterpretParams(dma_execution_mode="eager") \
-        if interpret else False
-    # CompilerParams was TPUCompilerParams before the rename
-    cp_cls = getattr(pltpu, "CompilerParams",
-                     getattr(pltpu, "TPUCompilerParams", None))
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((n,) + x.shape[1:], x.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
-        interpret=ip,
-        compiler_params=cp_cls(
-            collective_id=7) if not interpret and cp_cls else None,
+        interpret=(pltpu.InterpretParams(dma_execution_mode="eager")
+                   if interpret else False),
+        compiler_params=(None if interpret
+                         else pltpu.CompilerParams(collective_id=7)),
     )(x)

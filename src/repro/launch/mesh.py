@@ -45,9 +45,22 @@ def make_host_mesh(model: Optional[int] = None) -> Optional[Mesh]:
     return make_mesh((data, model), ("data", "model"))
 
 
-# Hardware constants (TPU v5e) used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12          # per chip
-HBM_BW = 819e9                    # B/s per chip
-ICI_LINK_BW = 50e9                # B/s per link per direction
-ICI_AXIS_BW = 2 * ICI_LINK_BW     # ring uses both directions of an axis
-HBM_BYTES = 16 * 1024 ** 3        # 16 GiB per chip
+# Published per-chip peaks used by the roofline analysis, keyed by
+# ``jax.Device.device_kind`` (Google Cloud documentation, "TPU v5e").
+# The dry-run models a v5e pod.  A kind missing here is an error.
+PEAKS = {
+    "TPU v5 lite": {
+        "flops_bf16": 197e12,          # FLOP/s
+        "hbm_bw": 819e9,               # B/s
+        "ici_link_bw": 50e9,           # B/s per link per direction
+    },
+}
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Published peaks of one ``device_kind`` chip."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]

@@ -14,6 +14,7 @@ import json
 import jax
 
 from repro.configs.base import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compilation_cache
 from repro.launch.mesh import make_host_mesh
 from repro.runtime import FailureInjector, TrainConfig, Trainer
 
@@ -37,6 +38,7 @@ def main() -> None:
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args()
 
+    enable_compilation_cache()
     cfg = get_smoke_config(args.arch) if args.smoke \
         else get_config(args.arch)
     tcfg = TrainConfig(

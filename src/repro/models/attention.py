@@ -504,7 +504,7 @@ def attn_decode_sharded(cfg: Any, q: jax.Array, k_new: jax.Array,
     over ``model``; each shard updates its local rows and computes a
     partial softmax, combined with pmax/psum (flash-decoding)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from repro.compat import shard_map
     from repro.parallel.sharding import active_mesh
     mesh = active_mesh()
     b = q.shape[0]
@@ -533,10 +533,10 @@ def attn_decode_sharded(cfg: Any, q: jax.Array, k_new: jax.Array,
         return out.astype(q_.dtype), ck, cv
 
     out, ck, cv = shard_map(
-        body, mesh=mesh,
+        body, mesh,
         in_specs=(qspec, qspec, qspec, cspec, cspec, P()),
-        out_specs=(P(bspec, None, None, None, None), cspec, cspec),
-        check_rep=False)(q, k_new, v_new, cache["k"], cache["v"], length)
+        out_specs=(P(bspec, None, None, None, None), cspec, cspec))(
+            q, k_new, v_new, cache["k"], cache["v"], length)
     # out [B,Hkv,G,1,dv] -> [B,1,Hq,dv]
     b_, hkv, g, _, dv = out.shape
     y = jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(b_, 1, hkv * g, dv)

@@ -207,7 +207,7 @@ def _mla_decode_sharded(cfg: Any, p: PyTree, x: jax.Array,
     along seq over ``model``; partial softmax combined flash-decoding
     style (see attention.attn_decode_sharded)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from repro.compat import shard_map
     from repro.parallel.sharding import active_mesh
     from .attention import (_dp_prefix, _flash_decode_combine,
                             _local_row_update)
@@ -244,12 +244,12 @@ def _mla_decode_sharded(cfg: Any, p: PyTree, x: jax.Array,
         return o.astype(ql.dtype), ckv, krope
 
     o_lat, ckv, krope = shard_map(
-        body, mesh=mesh,
+        body, mesh,
         in_specs=(P(bspec, None, None, None), P(bspec, None, None, None),
                   P(bspec, None, None), P(bspec, None, None), c3, c3, P()),
-        out_specs=(P(bspec, None, None, None), c3, c3),
-        check_rep=False)(q_lat, q_rope, c_new, kr_new,
-                         cache["ckv"], cache["krope"], length)
+        out_specs=(P(bspec, None, None, None), c3, c3))(
+            q_lat, q_rope, c_new, kr_new, cache["ckv"], cache["krope"],
+            length)
     o_lat = jnp.moveaxis(o_lat, 1, 2)               # [B,1,H,kv_lora]
     wuv = p["w_uv"]["w"].reshape(cfg.kv_lora_rank, H, cfg.v_head_dim)
     out = jnp.einsum("bqhl,lhd->bqhd", o_lat, wuv.astype(x.dtype))

@@ -274,7 +274,7 @@ def _moe_ep(cfg: Any, p: PyTree, x: jax.Array, mesh: Any
     """shard_map wrapper: tokens sequence-sharded over the EP axis when
     S divides, token-sliced inside the region otherwise (decode)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from repro.compat import shard_map
     from repro.parallel.sharding import dp_axes, ep_axis_name
     ep_ax = ep_axis_name()
     ep = mesh.shape[ep_ax]
@@ -307,8 +307,8 @@ def _moe_ep(cfg: Any, p: PyTree, x: jax.Array, mesh: Any
             return y.reshape(x_.shape), lax.pmean(aux, ep_ax)
 
         y, aux = shard_map(
-            body, mesh=mesh, in_specs=(p_specs, x_spec),
-            out_specs=(x_spec, P()), check_rep=False)(p_ep, x)
+            body, mesh, in_specs=(p_specs, x_spec),
+            out_specs=(x_spec, P()))(p_ep, x)
         return y.reshape(-1, d), aux
 
     # decode / non-divisible: tokens replicated over EP axis; each rank
@@ -331,8 +331,8 @@ def _moe_ep(cfg: Any, p: PyTree, x: jax.Array, mesh: Any
         return yp[:T].reshape(x_.shape), lax.pmean(aux, ep_ax)
 
     y, aux = shard_map(
-        body, mesh=mesh, in_specs=(p_specs, x_spec),
-        out_specs=(x_spec, P()), check_rep=False)(p_ep, x)
+        body, mesh, in_specs=(p_specs, x_spec),
+        out_specs=(x_spec, P()))(p_ep, x)
     return y.reshape(-1, d), aux
 
 
@@ -394,7 +394,7 @@ def _moe_resident_decode(cfg: Any, p: PyTree, x_flat: jax.Array,
     (zero weight movement), and the combined output is one small psum
     over the expert-owner axes."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from repro.compat import shard_map
     E = cfg.n_experts
     d = x_flat.shape[-1]
     axes, n_owner = resident_axes(mesh, E)
@@ -419,6 +419,6 @@ def _moe_resident_decode(cfg: Any, p: PyTree, x_flat: jax.Array,
         return combine(yb, info, d), aux
 
     y, aux = shard_map(
-        body, mesh=mesh, in_specs=(p_specs, P(None, None)),
-        out_specs=(P(None, None), P()), check_rep=False)(p_ep, x_flat)
+        body, mesh, in_specs=(p_specs, P(None, None)),
+        out_specs=(P(None, None), P()))(p_ep, x_flat)
     return y, aux

@@ -3,10 +3,9 @@
 The scan-over-layers stack is split across a ``pipe`` mesh axis (each
 rank owns ``n_periods / pipe`` periods) and executed with the GPipe
 microbatch schedule built on LCX send/recv
-(`repro.parallel.pipeline.gpipe`).  The shard_map is *partial-manual*
-(``axis_names={"pipe"}``): inside a stage, GSPMD still applies the
-data/model sharding rules (FSDP × TP/SP), so PP composes with the rest
-of the parallelism stack.
+(`repro.parallel.pipeline.gpipe`).  The shard_map is manual on every
+mesh axis: inside a stage, activations are replicated across the
+non-pipe axes and the logical sharding constraints resolve to no-ops.
 
 Autodiff through the GPipe schedule IS GPipe training (forward all
 microbatches, backward in reverse — the ppermute transposes to the
@@ -75,12 +74,8 @@ def pp_apply_model(cfg: Any, params: PyTree, tokens: jax.Array, *,
     from repro.compat import shard_map
     stack_spec = jax.tree.map(lambda _: P("pipe"), params["stack"])
     ranks = jnp.arange(pipe, dtype=jnp.int32)
-    # Fully-manual shard_map: the pinned XLA release hard-aborts on
-    # ppermute under partial-manual SPMD partitioning (and axis_index
-    # lowers to PartitionId, which it rejects outright) — so the region
-    # is manual on every mesh axis, with activations replicated across
-    # non-pipe axes.  Partial-manual ({"pipe"} only) restores intra-stage
-    # GSPMD once the toolchain moves past that bug.
+    # Fully-manual shard_map: the region is manual on every mesh axis,
+    # with activations replicated across the non-pipe axes.
     out_micro = shard_map(
         region, mesh=mesh, in_specs=(stack_spec, P(), P("pipe")),
         out_specs=P(), check=False)(params["stack"], micro, ranks)

@@ -77,8 +77,8 @@ def make_decode_fn(cfg: Any, kernels: Optional[Dict[str, Any]] = None):
     batch (the model's scalar-length path is the uniform-batch special
     case used by the decode_* dry-run cells)."""
 
-    def step(params: PyTree, tokens: jax.Array, caches: PyTree,
-             lengths: jax.Array) -> Tuple[jax.Array, PyTree]:
+    def decode_slots(params: PyTree, tokens: jax.Array, caches: PyTree,
+                     lengths: jax.Array) -> Tuple[jax.Array, PyTree]:
         def one(p, tok, cache, ln):
             # vmap stripped the slot dim; re-add a batch dim of 1 at the
             # per-leaf batch axis for the model's batched decode
@@ -97,7 +97,7 @@ def make_decode_fn(cfg: Any, kernels: Optional[Dict[str, Any]] = None):
             out_axes=(0, cache_axes))(params, tokens, caches, lengths)
         return lg, new_caches
 
-    return step
+    return decode_slots
 
 
 class ServingEngine:
@@ -162,11 +162,13 @@ class ServingEngine:
     def _free_slots(self) -> List[int]:
         return [i for i, r in enumerate(self.slot_req) if r is None]
 
-    def _prefill_fn(self, plen: int):
+    def prefill_program(self, plen: int):
+        """The jitted prefill of one slot for prompts of length ``plen``:
+        (params, tokens [plen], slot cache) -> (last logits [V], cache)."""
         if plen not in self._prefill_cache:
             cfg, kernels = self.cfg, self.kernels
 
-            def one(params, toks, cache):
+            def prefill_slot(params, toks, cache):
                 axes = cache_batch_axes(cfg, cache)
                 cache_b = jax.tree.map(jnp.expand_dims, cache, axes)
                 lg, nc = prefill(cfg, params, toks[None], cache_b,
@@ -174,7 +176,7 @@ class ServingEngine:
                 nc = jax.tree.map(lambda t, a: jnp.squeeze(t, a), nc, axes)
                 return lg[0, -1], nc
 
-            self._prefill_cache[plen] = jax.jit(one)
+            self._prefill_cache[plen] = jax.jit(prefill_slot)
         return self._prefill_cache[plen]
 
     def _admit(self) -> None:
@@ -213,7 +215,7 @@ class ServingEngine:
             # exact-length prefill: one compiled program per distinct
             # prompt length (bucketing would corrupt SSM prefill state —
             # the recurrent state cannot mask padding the way KV rows can)
-            lg, new_cache = self._prefill_fn(plen)(
+            lg, new_cache = self.prefill_program(plen)(
                 self.params, toks, slot_cache)
         except Exception as e:
             # the shared cache was not written yet — evict the request

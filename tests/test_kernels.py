@@ -55,7 +55,8 @@ def test_ssd_scan_kernel(case):
     A = -jnp.exp(jax.random.normal(jax.random.fold_in(key, 2), (h,)))
     Bm = jax.random.normal(jax.random.fold_in(key, 3), (b, s, h, n), dtype)
     Cm = jax.random.normal(jax.random.fold_in(key, 4), (b, s, h, n), dtype)
-    y, hf = ops.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, backend="pallas")
+    y, hf = ops.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk,
+                         backend="interpret")
     y_ref, h_ref = kref.ssd_scan_ref(x, dt, A, Bm, Cm, chunk)
     tol = 1e-4 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(y, np.float32),
@@ -99,7 +100,7 @@ def test_model_kernels_hooks_match_model_layout():
     cfg = ModelConfig(n_layers=1, d_model=32, n_heads=4, n_kv_heads=2,
                       d_ff=64, vocab=64, dtype=jnp.float32,
                       param_dtype=jnp.float32, q_block=16)
-    ks = ops.model_kernels(cfg, backend="pallas")
+    ks = ops.model_kernels(cfg, backend="interpret")
     b, s = 2, 64
     q = jax.random.normal(jax.random.PRNGKey(0), (b, s, 4, 16))
     k = jax.random.normal(jax.random.PRNGKey(1), (b, s, 2, 16))
@@ -111,3 +112,12 @@ def test_model_kernels_hooks_match_model_layout():
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(jnp.swapaxes(ref, 1, 2)),
                                atol=2e-5)
+
+
+def test_pallas_backend_refuses_cpu():
+    # compiled Mosaic needs the TPU; interpret mode is asked for by name
+    q = jnp.zeros((1, 2, 16, 8))
+    with pytest.raises(RuntimeError, match="backend='interpret'"):
+        ops.flash_attention(q, q, q, backend="pallas")
+    with pytest.raises(RuntimeError, match="backend='interpret'"):
+        ops.model_kernels(None, backend="pallas")

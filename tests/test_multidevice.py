@@ -10,14 +10,13 @@ import subprocess
 import sys
 import textwrap
 
-import pytest
-
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
 def run_sub(code: str, devices: int = 8, timeout: int = 420) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env,
@@ -56,13 +55,6 @@ def test_moe_ep_lcx_matches_local_oracle():
 
 
 def test_ring_allgather_pallas_kernel():
-    # Pinned-jax note: interpret mode needs pltpu.InterpretParams and
-    # pltpu.sync_copy, which only exist on newer JAX releases; on this
-    # pin the kernel is TPU-hardware-only.
-    from repro.kernels.ring_allgather import tpu_interpret_available
-    if not tpu_interpret_available():
-        pytest.skip("pinned JAX lacks pltpu TPU interpret machinery "
-                    "(InterpretParams/sync_copy)")
     run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
@@ -70,7 +62,8 @@ def test_ring_allgather_pallas_kernel():
         from repro.compat import make_mesh, shard_map
         mesh = make_mesh((8,), ("x",))
         x = jnp.arange(8 * 16, dtype=jnp.float32).reshape(8, 16)
-        f = shard_map(lambda s: ring_all_gather(s, "x", axis_size=8),
+        f = shard_map(lambda s: ring_all_gather(s, "x", axis_size=8,
+                                                 interpret=True),
                       mesh, in_specs=P("x", None),
                       out_specs=P("x", None))
         out = jax.jit(f)(x)

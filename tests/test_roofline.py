@@ -108,3 +108,10 @@ def test_moe_active_params_scaled():
     # routed experts contribute k/E of their params
     expert_params = 3 * 8 * 64 * 64 * 2   # gate/up/down x E x d x f x 2 layers
     assert act < total - expert_params * 0.5
+
+
+def test_peaks_refuse_an_unknown_device_kind():
+    from repro.launch.mesh import DRYRUN_DEVICE_KIND, peaks
+    assert peaks(DRYRUN_DEVICE_KIND)["flops_bf16"] == 197e12
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("cpu")

@@ -117,3 +117,41 @@ def test_temperature_sampling_varies(dense_setup):
         eng.submit(Request(rid=0, prompt=np.arange(5, dtype=np.int32)))
         outs.add(tuple(eng.run_until_drained()[0].output))
     assert len(outs) > 1
+
+
+def _run_serve_cli(tmp_path, patch: str = ""):
+    """``python -m repro.launch.serve`` on the smoke config in a child,
+    with ``patch`` run first; returns the finished process."""
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    code = (f"import sys\n{patch}\nfrom repro.launch import serve\n"
+            "sys.exit(serve.main(['--arch', 'qwen2-0.5b', '--requests', "
+            "'3', '--max-new', '3']))\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"),
+               PYTHONPATH=os.path.abspath(src))
+    return subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=300)
+
+
+def test_serve_cli_exits_zero_when_every_request_served(tmp_path):
+    out = _run_serve_cli(tmp_path)
+    assert out.returncode == 0, out.stderr
+    assert "served 3 requests, 9 tokens" in out.stdout
+
+
+def test_serve_cli_exits_nonzero_when_prefill_evicted(tmp_path):
+    # every prefill raises: the engine evicts the requests, and the
+    # entry point must say so and fail instead of reporting success
+    out = _run_serve_cli(tmp_path, patch=(
+        "from repro.serving import ServingEngine\n"
+        "def broken(self, plen):\n"
+        "    def prefill(*args):\n"
+        "        raise RuntimeError('injected prefill failure')\n"
+        "    return prefill\n"
+        "ServingEngine.prefill_program = broken\n"))
+    assert out.returncode == 1, out.stdout + out.stderr
+    assert "evicted: prefill failed" in out.stderr
+    assert "injected prefill failure" in out.stderr
