@@ -1,0 +1,170 @@
+"""Whole runs of ``bench/run.py``: refused without a chip, and, past the
+look for a chip, driven on the CPU at a small size with the timed path
+sound and then broken underneath, where ``correct`` must come out
+false."""
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+import time
+
+import jax
+import pytest
+
+from bench import harness
+from bench.run import execute
+from bench.tests import tiny
+
+RUN = os.path.join(harness.BENCH_DIR, "run.py")
+
+
+def cpu_env(**extra):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(harness.ROOT, "src"), **extra)
+    env.pop("ALLOW_MULTIPLE_LIBTPU_LOAD", None)
+    return env
+
+
+def last_json(stdout: str):
+    lines = [l for l in stdout.splitlines() if l.startswith("{")]
+    return json.loads(lines[-1]) if lines else None
+
+
+def test_refuses_without_a_tpu():
+    p = subprocess.run([sys.executable, RUN, "--workload", "qwen2-0.5b.chat",
+                        "--seed", "1", "--seconds", "1"], cwd=harness.ROOT,
+                       env=cpu_env(), capture_output=True, text=True,
+                       timeout=120)
+    assert p.returncode == 2
+    assert last_json(p.stdout) is None
+    assert "no TPU" in p.stderr
+
+
+def test_refuses_with_only_the_benchmark_files(tmp_path):
+    shutil.copy(os.path.join(harness.ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(harness.BENCH_DIR, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    p = subprocess.run([sys.executable, "bench/run.py", "--workload",
+                        "qwen2-0.5b.chat", "--seed", "1", "--seconds", "1"],
+                       cwd=tmp_path, env=dict(cpu_env(), PYTHONPATH=""),
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode != 0
+    assert last_json(p.stdout) is None
+
+
+def serve_once(workload, seed):
+    cell = tiny.serving_cell(*workload)
+    line = execute(cell, jax.devices()[:1], seed, 1.5, False,
+                   {"logit_gap": 0.05}, time.perf_counter())
+    return json.loads(line)
+
+
+@pytest.fixture
+def no_cache(monkeypatch, tmp_path):
+    # JAX read its cache settings when it started: naming a directory
+    # now keeps the helper from switching the cache on for these runs
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+
+
+SERVED = [("qwen2-0.5b", "chat"), ("qwen2-0.5b", "batch"),
+          ("starcoder2-7b", "code")]
+
+
+@pytest.mark.parametrize("workload", SERVED)
+def test_sound_run_is_correct(no_cache, workload):
+    res = serve_once(workload, 2**33 + 1)
+    assert res["correct"] is True and res["failed"] == 0
+    assert list(res)[-1] == "checks"
+    assert res["checks"]["logit_gap"]["value"] <= 0.05
+    assert "setup_s" in res["metrics"] and "itl_p95_ms" in res["metrics"]
+    assert res["device"]["platform"] == "cpu"
+
+
+@pytest.mark.parametrize("workload", SERVED)
+def test_altered_token_is_caught(no_cache, monkeypatch, workload):
+    """A token altered where the engine produces it."""
+    import repro.serving.engine as engine
+    sample = engine.sample_token
+
+    def altered(logits, temperature, key):
+        return (sample(logits, temperature, key) + 1) % logits.shape[-1]
+
+    monkeypatch.setattr(engine, "sample_token", altered)
+    res = serve_once(workload, 3)
+    assert res["correct"] is False
+    assert res["checks"]["logit_gap"]["value"] > \
+        res["checks"]["logit_gap"]["limit"]
+
+
+RING = """
+import json, sys, time
+sys.path[:0] = [{root!r}, {src!r}]
+import jax
+from bench import harness
+from bench.run import execute
+import repro.core as lcx
+if sys.argv[1] == "broken":
+    # the exchange between chips left out: every put goes to itself
+    shift = lcx.Perm.shift
+    lcx.Perm.shift = staticmethod(lambda k: shift(0))
+cell = harness.Cell(
+    "lcx-ring4.pingpong", 4,
+    harness.load_json(harness.BENCH_DIR + "/configs/lcx-ring4.json"),
+    harness.load_json(harness.BENCH_DIR + "/traffic/pingpong.json"),
+    [{{"name": "msg_rate", "unit": "msgs/s"}}])
+print(execute(cell, jax.devices()[:4], 2**34 + 9, 1.0, False,
+              {{"wrong_payloads": 0}}, time.perf_counter()))
+"""
+
+
+@pytest.mark.parametrize("mode", ["sound", "broken"])
+def test_ring_exchange_left_out_is_caught(tmp_path, mode):
+    script = tmp_path / "ring.py"
+    script.write_text(RING.format(root=harness.ROOT,
+                                  src=os.path.join(harness.ROOT, "src")))
+    p = subprocess.run(
+        [sys.executable, str(script), mode], cwd=tmp_path,
+        env=cpu_env(XLA_FLAGS="--xla_force_host_platform_device_count=4",
+                    JAX_COMPILATION_CACHE_DIR=str(tmp_path)),
+        capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-3000:]
+    res = last_json(p.stdout)
+    assert res["correct"] is (mode == "sound")
+    assert res["metrics"]["msg_rate"]["value"] > 0
+    assert (res["failed"] == 0) is (mode == "sound")
+
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+
+
+def test_every_entry_is_found_by_name():
+    """What BENCHMARK.json names exists as a file of its own."""
+    b = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
+    e2e = {m["name"]: m for m in b["end_to_end"]}
+    for c in b["configs"]:
+        assert NAME.match(c["name"])
+        assert os.path.exists(os.path.join(harness.ROOT, c["file"]))
+    for w in b["workloads"]:
+        assert NAME.match(w["name"]) and w["chips"] in (1, 4)
+        assert os.path.exists(os.path.join(
+            harness.BENCH_DIR, "traffic", w["traffic"] + ".json"))
+        assert os.path.exists(os.path.join(
+            harness.BENCH_DIR, "limits", w["name"] + ".json"))
+        reported = harness.cell_metrics(b, w["name"], False)
+        names = {m["name"] for m in reported}
+        assert "setup_s" in names and len(names) >= 2
+        per_layer = harness.cell_metrics(b, w["name"], True)
+        assert per_layer and all(m["moves"] in names for m in per_layer)
+    for m in b["end_to_end"] + b["per_layer"]:
+        assert NAME.match(m["name"])
+        harness.load_reader(m["name"])
+    for m in b["per_layer"]:
+        assert m["moves"] in e2e
+        # every per-layer metric lists its cells, and each reports the
+        # end-to-end metric it moves
+        assert m["workloads"]
+        for w in m["workloads"]:
+            reported = harness.cell_metrics(b, w, False)
+            assert m["moves"] in {x["name"] for x in reported}
