@@ -34,6 +34,8 @@ import heapq
 import itertools
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 import repro.core as lcx
 
 from .task import Task, TaskGraph, TaskState
@@ -289,6 +291,10 @@ class Executor:
         """Drain the graph: execute ready tasks, interleave progress,
         retire completions.  Raises on deadlock (blocked tasks that no
         amount of progress can unblock)."""
+        with TraceAnnotation("amt.run", tasks=len(self.graph)):
+            return self._drain(max_cycles)
+
+    def _drain(self, max_cycles: int) -> Dict[str, int]:
         for t in self.graph.newly_ready():
             self._push(t)
         for _ in range(max_cycles):
@@ -362,7 +368,8 @@ class Executor:
         task.state = TaskState.RUNNING
         ctx = TaskContext(self, task)
         try:
-            out = task.fn(ctx)
+            with TraceAnnotation("amt.task", name=task.name):
+                out = task.fn(ctx)
         except BaseException as e:
             if self.fail_fast or not isinstance(e, Exception):
                 self.graph.fail(task, e)

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from repro.models import decode_step, init_cache, prefill
 from repro.models.model import cache_batch_axes
@@ -58,7 +59,6 @@ class Request:
     done: bool = False
     error: Optional[str] = None        # set when the request was evicted
     submitted_at: float = 0.0
-    finished_at: float = 0.0
 
 
 def sample_token(logits: jax.Array, temperature: float,
@@ -189,7 +189,6 @@ class ServingEngine:
         loop keeps serving the other slots instead of wedging."""
         req.done = True
         req.error = reason
-        req.finished_at = time.perf_counter()
         self.finished.append(req)
         self.failed.append(req)
         self.stats["evictions"] += 1
@@ -203,50 +202,56 @@ class ServingEngine:
             return False
         slot = free[0]
         plen = len(req.prompt)
-        if plen >= self.scfg.max_seq:
-            self._evict(req, f"prompt length {plen} >= max_seq "
-                             f"{self.scfg.max_seq}")
+        with TraceAnnotation("serve.admit", rid=req.rid, plen=plen,
+                             slot=slot):
+            if plen >= self.scfg.max_seq:
+                self._evict(req, f"prompt length {plen} >= max_seq "
+                                 f"{self.scfg.max_seq}")
+                return True
+            axes = cache_batch_axes(self.cfg, self.caches)
+            try:
+                with TraceAnnotation("serve.prefill", plen=plen):
+                    toks = jnp.asarray(req.prompt, jnp.int32)
+                    slot_cache = jax.tree.map(
+                        lambda t, a: jnp.take(t, slot, axis=a), self.caches,
+                        axes)
+                    # exact-length prefill: one compiled program per distinct
+                    # prompt length (bucketing would corrupt SSM prefill state
+                    # — the recurrent state cannot mask padding the way KV
+                    # rows can)
+                    lg, new_cache = self.prefill_program(plen)(
+                        self.params, toks, slot_cache)
+            except Exception as e:
+                # the shared cache was not written yet — evict the request
+                # and leave the slot free for the next one
+                self._evict(req, f"prefill failed: {type(e).__name__}: {e}")
+                return True
+            with TraceAnnotation("serve.slot_write", slot=slot):
+                self.caches = jax.tree.map(
+                    lambda buf, nc, a: jax.lax.dynamic_update_slice_in_dim(
+                        buf, jnp.expand_dims(nc, a).astype(buf.dtype),
+                        slot, axis=a),
+                    self.caches, new_cache, axes)
+            self.lengths[slot] = plen
+            self.slot_req[slot] = req
+            self.stats["prefills"] += 1
+            # sample the first generated token from the prefill logits
+            self._key, sub = jax.random.split(self._key)
+            with TraceAnnotation("serve.sync", what="first"):
+                tok = int(np.asarray(sample_token(
+                    lg[None], self.scfg.temperature, sub))[0])
+            req.output.append(tok)
+            self.stats["decoded_tokens"] += 1
+            # the first token may already terminate the request
+            limit = req.max_new_tokens or self.scfg.max_new_tokens
+            if (self.scfg.eos_token is not None
+                    and tok == self.scfg.eos_token) \
+                    or len(req.output) >= limit:
+                req.done = True
+                self.finished.append(req)
+                self.slot_req[slot] = None
+                self.lengths[slot] = 0
             return True
-        axes = cache_batch_axes(self.cfg, self.caches)
-        try:
-            toks = jnp.asarray(req.prompt, jnp.int32)
-            slot_cache = jax.tree.map(
-                lambda t, a: jnp.take(t, slot, axis=a), self.caches, axes)
-            # exact-length prefill: one compiled program per distinct
-            # prompt length (bucketing would corrupt SSM prefill state —
-            # the recurrent state cannot mask padding the way KV rows can)
-            lg, new_cache = self.prefill_program(plen)(
-                self.params, toks, slot_cache)
-        except Exception as e:
-            # the shared cache was not written yet — evict the request
-            # and leave the slot free for the next one
-            self._evict(req, f"prefill failed: {type(e).__name__}: {e}")
-            return True
-        self.caches = jax.tree.map(
-            lambda buf, nc, a: jax.lax.dynamic_update_slice_in_dim(
-                buf, jnp.expand_dims(nc, a).astype(buf.dtype),
-                slot, axis=a),
-            self.caches, new_cache, axes)
-        self.lengths[slot] = plen
-        self.slot_req[slot] = req
-        self.stats["prefills"] += 1
-        # sample the first generated token from the prefill logits
-        self._key, sub = jax.random.split(self._key)
-        tok = int(np.asarray(sample_token(
-            lg[None], self.scfg.temperature, sub))[0])
-        req.output.append(tok)
-        self.stats["decoded_tokens"] += 1
-        # the first token may already terminate the request
-        limit = req.max_new_tokens or self.scfg.max_new_tokens
-        if (self.scfg.eos_token is not None
-                and tok == self.scfg.eos_token) \
-                or len(req.output) >= limit:
-            req.done = True
-            req.finished_at = time.perf_counter()
-            self.finished.append(req)
-            self.slot_req[slot] = None
-            self.lengths[slot] = 0
-        return True
 
     # -- decode tick ----------------------------------------------------------
     def tick(self) -> int:
@@ -256,10 +261,11 @@ class ServingEngine:
         With an executor, admission and decode run as a per-tick task
         graph: one prefill-admission task per queued request (priority
         keeps arrival order) feeding one decode task."""
-        if self._executor is not None:
-            return self._tick_executor()
-        self._admit()
-        return self._decode_tick()
+        with TraceAnnotation("serve.tick", queued=len(self.queue)):
+            if self._executor is not None:
+                return self._tick_executor()
+            self._admit()
+            return self._decode_tick()
 
     def _tick_executor(self) -> int:
         ex = self._executor
@@ -283,35 +289,36 @@ class ServingEngine:
         active = [i for i, r in enumerate(self.slot_req) if r is not None]
         if not active:
             return 0
-        tokens = np.zeros((self.scfg.n_slots, 1), np.int32)
-        for i in active:
-            req = self.slot_req[i]
-            tokens[i, 0] = req.output[-1] if req.output \
-                else req.prompt[-1]
-        lengths = jnp.asarray(self.lengths)
-        lg, self.caches = self._decode(self.params, jnp.asarray(tokens),
-                                       self.caches, lengths)
-        self._key, sub = jax.random.split(self._key)
-        nxt = np.asarray(sample_token(lg[:, 0] if lg.ndim == 3 else lg,
-                                      self.scfg.temperature, sub))
-        self.stats["ticks"] += 1
-        for i in active:
-            req = self.slot_req[i]
-            self.lengths[i] += 1
-            tok = int(nxt[i])
-            req.output.append(tok)
-            self.stats["decoded_tokens"] += 1
-            limit = req.max_new_tokens or self.scfg.max_new_tokens
-            if (self.scfg.eos_token is not None
-                    and tok == self.scfg.eos_token) \
-                    or len(req.output) >= limit \
-                    or self.lengths[i] >= self.scfg.max_seq - 1:
-                req.done = True
-                req.finished_at = time.perf_counter()
-                self.finished.append(req)
-                self.slot_req[i] = None
-                self.lengths[i] = 0
-        return len(active)
+        with TraceAnnotation("serve.decode", active=len(active)):
+            tokens = np.zeros((self.scfg.n_slots, 1), np.int32)
+            for i in active:
+                req = self.slot_req[i]
+                tokens[i, 0] = req.output[-1] if req.output \
+                    else req.prompt[-1]
+            lengths = jnp.asarray(self.lengths)
+            lg, self.caches = self._decode(self.params, jnp.asarray(tokens),
+                                           self.caches, lengths)
+            self._key, sub = jax.random.split(self._key)
+            with TraceAnnotation("serve.sync", what="decode"):
+                nxt = np.asarray(sample_token(lg[:, 0] if lg.ndim == 3 else lg,
+                                              self.scfg.temperature, sub))
+            self.stats["ticks"] += 1
+            for i in active:
+                req = self.slot_req[i]
+                self.lengths[i] += 1
+                tok = int(nxt[i])
+                req.output.append(tok)
+                self.stats["decoded_tokens"] += 1
+                limit = req.max_new_tokens or self.scfg.max_new_tokens
+                if (self.scfg.eos_token is not None
+                        and tok == self.scfg.eos_token) \
+                        or len(req.output) >= limit \
+                        or self.lengths[i] >= self.scfg.max_seq - 1:
+                    req.done = True
+                    self.finished.append(req)
+                    self.slot_req[i] = None
+                    self.lengths[i] = 0
+            return len(active)
 
     def run_until_drained(self, max_ticks: int = 10000) -> List[Request]:
         for _ in range(max_ticks):

@@ -155,3 +155,83 @@ def test_serve_cli_exits_nonzero_when_prefill_evicted(tmp_path):
     assert out.returncode == 1, out.stdout + out.stderr
     assert "evicted: prefill failed" in out.stderr
     assert "injected prefill failure" in out.stderr
+
+
+# -- spans and counters -------------------------------------------------------
+SPANS = ("serve.tick", "amt.run", "amt.task", "serve.admit", "serve.prefill",
+         "serve.slot_write", "serve.sync", "serve.decode")
+
+
+@pytest.fixture(scope="module")
+def traced(dense_setup, tmp_path_factory):
+    """One engine drained under the profiler: (engine, spans), each span
+    (name, start ns, end ns, args) from the host plane."""
+    import glob
+    from jax.profiler import ProfileData
+    cfg, params = dense_setup
+    eng = ServingEngine(cfg, params, ServeConfig(n_slots=3, max_seq=64,
+                                                 max_new_tokens=6))
+    reqs = [Request(rid=i, prompt=np.arange(4 + i % 4, dtype=np.int32),
+                    max_new_tokens=1 + i % 5) for i in range(8)]
+    for r in reqs:
+        eng.submit(r)
+    d = str(tmp_path_factory.mktemp("trace"))
+    with jax.profiler.trace(d):
+        eng.run_until_drained()
+    pd = ProfileData.from_file(glob.glob(f"{d}/**/*.xplane.pb",
+                                         recursive=True)[0])
+    spans = [(e.name, e.start_ns, e.end_ns, dict(e.stats))
+             for plane in pd.planes if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name in SPANS]
+    return eng, spans
+
+
+def _named(spans, span, **args):
+    return [s for s in spans if s[0] == span and
+            all(s[3].get(k) == v for k, v in args.items())]
+
+
+def _inside(child, parents):
+    return any(p[1] <= child[1] and child[2] <= p[2] for p in parents)
+
+
+def test_every_span_is_traced(traced):
+    _, spans = traced
+    assert {s[0] for s in spans} == set(SPANS)
+    assert all(set(s[3]) == {"queued"} for s in _named(spans, "serve.tick"))
+
+
+def test_spans_nest_in_their_cause(traced):
+    _, spans = traced
+    ticks, runs = _named(spans, "serve.tick"), _named(spans, "amt.run")
+    for admit in _named(spans, "serve.admit"):
+        # the admission task of the same request holds it
+        task = _named(spans, "amt.task", name=f"prefill:{admit[3]['rid']}")
+        assert _inside(admit, task)
+        assert _inside(admit, runs) and _inside(admit, ticks)
+        assert set(admit[3]) == {"rid", "plen", "slot"}
+    admits = _named(spans, "serve.admit")
+    for name in ("serve.prefill", "serve.slot_write"):
+        assert all(_inside(s, admits) for s in _named(spans, name))
+    assert all(_inside(s, admits)
+               for s in _named(spans, "serve.sync", what="first"))
+    decodes = _named(spans, "serve.decode")
+    assert all(_inside(d, _named(spans, "amt.task", name="decode"))
+               for d in decodes)
+    assert all(_inside(s, decodes)
+               for s in _named(spans, "serve.sync", what="decode"))
+    assert all(_inside(t, runs) for t in _named(spans, "amt.task"))
+    assert all(_inside(r, ticks) for r in runs)
+
+
+def test_span_counts_match_the_engine_counters(traced):
+    eng, spans = traced
+    assert len(_named(spans, "serve.admit")) == eng.stats["prefills"] == 8
+    assert len(_named(spans, "serve.decode")) == eng.stats["ticks"]
+    assert len(_named(spans, "serve.sync")) == \
+        eng.stats["prefills"] + eng.stats["ticks"]
+    # amt.run carries the size of the graph it drains
+    last = max(_named(spans, "amt.run"), key=lambda r: r[1])
+    assert last[3]["tasks"] == len(eng._executor.graph)
+
