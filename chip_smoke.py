@@ -9,11 +9,12 @@ d_model 896, 14/2 GQA heads, d_ff 4864, vocab 151936) with parameters
 drawn from ``--seed`` and serves 8 requests on 4 slots through the code
 that ``python -m repro.launch.serve --full`` runs: admission through the
 AMT executor, prefill through the Pallas flash kernel, decode through
-the vmapped decode step.  The 8 prompts are served twice, cold and then
-warm.  Checks: every request returned 32 tokens and none was evicted;
-the warm pass repeats the cold one token for token; the prefill program
-holds a Mosaic kernel (``tpu_custom_call``); its logits agree with the
-XLA prefill within a bf16 tolerance.
+one batched step with a length per slot.  The 8 prompts are served
+twice, cold and then warm.  Checks: every request returned 32 tokens
+and none was evicted; the warm pass repeats the cold one token for
+token; the prefill program holds a Mosaic kernel
+(``tpu_custom_call``); its logits agree with the XLA prefill within a
+bf16 tolerance.
 
 Four chips.  The three ping-pong designs with every payload checked,
 the LCX ring and pairwise collectives against the native ones bit for

@@ -555,10 +555,57 @@ def attn_cache_dims() -> PyTree:
             "v": ("cache_batch", "cache_seq", "kv_heads", "head")}
 
 
+def old_rows_mask(cache_seq: int, length: jax.Array,
+                  window: Optional[int] = None) -> jax.Array:
+    """[B, cache_seq] bool: the cache rows a decode at per-row lengths
+    [B] attends besides its new row, those before ``length[b]`` (and
+    inside the sliding ``window``).  Per-row lengths are for the
+    unsharded cache of the serving engine."""
+    if seq_sharded_decode(cache_seq):
+        raise NotImplementedError(
+            "decode with per-row lengths [B] needs an unsharded KV cache; "
+            "a sequence-sharded cache takes one scalar length")
+    dist = length[:, None] - jnp.arange(cache_seq, dtype=jnp.int32)[None, :]
+    ok = dist > 0
+    if window is not None:
+        ok &= dist < window
+    return ok
+
+
+def _attn_decode_rows(cfg: Any, p: PyTree, x: jax.Array, cache: PyTree,
+                      length: jax.Array) -> Tuple[jax.Array, PyTree]:
+    """``attn_decode`` with a length per row [B].  The cache is only
+    read: each row attends its old rows ``< length[b]`` and its new K/V
+    row, one extra logit and value term of the same softmax.  Returns
+    (y, the new row {k, v: [B,Hkv,hd]})."""
+    b = x.shape[0]
+    ok = old_rows_mask(cache["k"].shape[1], length, cfg.sliding_window)
+    q, k_new, v_new = _project_qkv(cfg, p, x, length[:, None])
+    scale = 1.0 / math.sqrt(cfg.head_dim)
+    s_old = _gqa_scores(q, cache["k"].astype(x.dtype)) * scale
+    s_old = jnp.where(ok[:, None, None, None, :], s_old, NEG_INF)
+    s_new = _gqa_scores(q, k_new) * scale               # [B,Hkv,G,1,1]
+    m = jnp.maximum(s_old.max(axis=-1, keepdims=True), s_new)
+    p_old, p_new = jnp.exp(s_old - m), jnp.exp(s_new - m)
+    l = p_old.sum(axis=-1, keepdims=True) + p_new
+    pv = "bhgqk,bkhd->bqhgd"
+    out = jnp.einsum(pv, (p_old / l).astype(x.dtype),
+                     cache["v"].astype(x.dtype),
+                     preferred_element_type=jnp.float32) \
+        + jnp.einsum(pv, p_new / l, v_new.astype(jnp.float32))
+    out = out.astype(x.dtype)
+    y = dense(p["wo"], out.reshape(b, 1, cfg.n_heads * cfg.head_dim))
+    return y, {"k": k_new[:, 0], "v": v_new[:, 0]}
+
+
 def attn_decode(cfg: Any, p: PyTree, x: jax.Array, cache: PyTree,
                 length: jax.Array) -> Tuple[jax.Array, PyTree]:
     """One decode step.  x [B,1,D]; cache k/v [B,Smax,Hkv,hd]; length []
-    (tokens already in cache).  Returns (y [B,1,D], new_cache)."""
+    (tokens already in cache).  Returns (y [B,1,D], new_cache).  A
+    length per row [B] returns only the new row instead
+    (``_attn_decode_rows``)."""
+    if jnp.ndim(length) == 1:
+        return _attn_decode_rows(cfg, p, x, cache, length)
     b = x.shape[0]
     positions = jnp.full((1,), length, jnp.int32)
     q, k_new, v_new = _project_qkv(cfg, p, x, positions)

@@ -163,7 +163,12 @@ def mla_decode(cfg: Any, p: PyTree, x: jax.Array, cache: PyTree,
 
     scores = q_nope @ w_uk^T @ ckv  +  q_rope @ k_rope
     out    = (attn @ ckv) @ w_uv
+
+    A length per row [B] returns only the new latent row
+    (``_mla_decode_rows``).
     """
+    if jnp.ndim(length) == 1:
+        return _mla_decode_rows(cfg, p, x, cache, length)
     b = x.shape[0]
     H = cfg.n_heads
     positions = jnp.full((1,), length, jnp.int32)
@@ -196,6 +201,47 @@ def mla_decode(cfg: Any, p: PyTree, x: jax.Array, cache: PyTree,
     out = jnp.einsum("bqhl,lhd->bqhd", o_lat, wuv.astype(x.dtype))
     y = dense(p["wo"], out.reshape(b, 1, H * cfg.v_head_dim))
     return y, {"ckv": ckv, "krope": krope}
+
+
+def _mla_decode_rows(cfg: Any, p: PyTree, x: jax.Array, cache: PyTree,
+                     length: jax.Array) -> Tuple[jax.Array, PyTree]:
+    """``mla_decode`` with a length per row [B].  The latent cache is
+    only read: each row attends its old rows ``< length[b]`` and its new
+    latent row in one softmax.  Returns (y, the new row {ckv: [B,kv_lora],
+    krope: [B,rope]})."""
+    from .attention import old_rows_mask
+    b = x.shape[0]
+    H = cfg.n_heads
+    ok = old_rows_mask(cache["ckv"].shape[1], length)
+    positions = length[:, None]
+    q_nope, q_rope = _queries(cfg, p, x, positions)   # [B,1,H,*]
+    c_new, kr_new = _latents(cfg, p, x, positions)    # [B,1,kv_lora/rope]
+    wuk = p["w_uk"]["w"].reshape(cfg.kv_lora_rank, H, cfg.qk_nope_head_dim)
+    q_lat = jnp.einsum("bqhd,lhd->bqhl", q_nope, wuk.astype(x.dtype))
+    scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+
+    def scores(ckv, krope):                            # -> [B,H,1,K]
+        s = jnp.einsum("bqhl,bkl->bhqk", q_lat, ckv.astype(x.dtype),
+                       preferred_element_type=jnp.float32)
+        return (s + jnp.einsum("bqhd,bkd->bhqk", q_rope,
+                               krope.astype(x.dtype),
+                               preferred_element_type=jnp.float32)) * scale
+
+    s_old = jnp.where(ok[:, None, None, :],
+                      scores(cache["ckv"], cache["krope"]), NEG_INF)
+    s_new = scores(c_new, kr_new)
+    m = jnp.maximum(s_old.max(axis=-1, keepdims=True), s_new)
+    p_old, p_new = jnp.exp(s_old - m), jnp.exp(s_new - m)
+    l = p_old.sum(axis=-1, keepdims=True) + p_new
+    o_lat = jnp.einsum("bhqk,bkl->bqhl", (p_old / l).astype(x.dtype),
+                       cache["ckv"].astype(x.dtype),
+                       preferred_element_type=jnp.float32) \
+        + jnp.einsum("bhqk,bkl->bqhl", p_new / l, c_new.astype(jnp.float32))
+    wuv = p["w_uv"]["w"].reshape(cfg.kv_lora_rank, H, cfg.v_head_dim)
+    out = jnp.einsum("bqhl,lhd->bqhd", o_lat.astype(x.dtype),
+                     wuv.astype(x.dtype))
+    y = dense(p["wo"], out.reshape(b, 1, H * cfg.v_head_dim))
+    return y, {"ckv": c_new[:, 0], "krope": kr_new[:, 0]}
 
 
 def _mla_decode_sharded(cfg: Any, p: PyTree, x: jax.Array,

@@ -17,6 +17,7 @@ Activation sharding constraints are applied at layer boundaries via
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -426,7 +427,7 @@ def cache_batch_axes(cfg: Any, caches: PyTree) -> PyTree:
     """Pytree (matching ``caches``) of the batch-dim index per leaf:
     0 for prefix-layer caches, 1 for scan-stacked caches (dim 0 is the
     period index there).  Used by the serving engine for slot indexing
-    and by vmapped decode."""
+    and by the per-row decode's cache update."""
     return {k: jax.tree.map(lambda _: 1 if k == "stack" else 0, v)
             for k, v in caches.items()}
 
@@ -450,11 +451,58 @@ def decode_step(cfg: Any, params: PyTree, tokens: jax.Array, caches: PyTree,
                 length: jax.Array, *,
                 kernels: Optional[Dict[str, Any]] = None
                 ) -> Tuple[jax.Array, PyTree]:
-    """One token for every sequence.  tokens [B, 1]; length [] = current
-    cache fill.  Returns (logits [B, 1, V], new caches)."""
+    """One token for every sequence.  tokens [B, 1]; length [] = the
+    cache fill of every row, or [B] = each row's own fill.  Returns
+    (logits [B, 1, V], new caches).
+
+    With a scalar length each layer writes its new K/V row into the
+    cache inside the layer scan.  With a vector length the cache is
+    read-only inside the scan: each attention layer attends its old
+    rows plus its own new row and returns only that row, and after the
+    scan one pass over the rows writes every layer's row at ``[layer, b,
+    length[b]]`` (in place when the caller donates the cache)."""
     x = _embed_in(cfg, params, tokens, None)
-    positions = jnp.full((1,), length, jnp.int32)
+    per_row = jnp.ndim(length) == 1
+    positions = length[:, None] if per_row \
+        else jnp.full((1,), length, jnp.int32)
     x, _, new_caches = _stack_sweep(cfg, params, x, positions=positions,
                                     mode="decode", caches=caches,
                                     length=length, kernels=kernels)
+    if per_row:
+        new_caches = _write_rows(cfg, caches, new_caches, length)
     return _head_out(cfg, params, x), new_caches
+
+
+# On a TPU the KV pool's sequence axis is its lane (minor) axis; a row
+# written at a dynamic position there makes XLA relayout the whole pool
+# around the write, a read-modify-write of the aligned block of positions
+# that holds it does not.
+ROW_BLOCK = 128
+
+
+def _write_rows(cfg: Any, caches: PyTree, new: PyTree,
+                length: jax.Array) -> PyTree:
+    """The caches after a per-row decode: each attention leaf gets its
+    new row ``new`` (the leaf less its sequence axis) written at
+    ``[..., b, length[b]]``, slot by slot in place; a recurrent state
+    (same shape as its leaf, no sequence axis) replaces the old one."""
+    def put(buf, row, axis):
+        if row.ndim == buf.ndim:
+            return row
+        blk = math.gcd(buf.shape[axis + 1], ROW_BLOCK)
+        shape = buf.shape[:axis] + (1, blk) + buf.shape[axis + 2:]
+        rest = (1,) * (row.ndim - axis - 1)
+
+        def one(b, buf):
+            s0 = length[b] // blk * blk
+            start = (0,) * axis + (b, s0) + (0,) * len(rest)
+            old = lax.dynamic_slice(buf, start, shape)
+            upd = jnp.expand_dims(lax.dynamic_slice_in_dim(row, b, 1, axis),
+                                  axis + 1).astype(buf.dtype)
+            hit = (jnp.arange(blk) == length[b] - s0).reshape((blk,) + rest)
+            return lax.dynamic_update_slice(buf, jnp.where(hit, upd, old),
+                                            start)
+
+        return lax.fori_loop(0, length.shape[0], one, buf)
+
+    return jax.tree.map(put, caches, new, cache_batch_axes(cfg, caches))

@@ -2,9 +2,10 @@
 
 The engine owns a fixed pool of ``n_slots`` sequences sharing one
 pre-allocated cache (`repro.models.init_cache`).  New requests prefill
-into free slots; every decode tick advances *all* active slots with one
-compiled ``decode_step`` (single-token, full-batch — the decode_* cells
-of the benchmark matrix lower exactly this function).
+into free slots; every decode tick advances *all* slots with one
+compiled ``decode_step`` (single-token, full-batch, a length per slot;
+the decode_* cells of the benchmark matrix lower its scalar-length
+form).
 
 Hardware note: prefill and decode are separate jit programs (different
 shapes); the decode program is cache-resident and memory-bound — its
@@ -12,7 +13,8 @@ roofline terms come from the dry-run of ``serve_step``.
 
 Per-slot state (lengths, completion) is host-side; the device-side
 decode uses per-slot length masks so slots at different positions can
-coexist in one batch (continuous batching).
+coexist in one batch (continuous batching), and writes each slot's new
+KV rows in place into the donated cache.
 
 Scheduling: each ``tick`` is driven through an AMT executor
 (`repro.amt.Executor`) — one admission task per queued request
@@ -71,31 +73,15 @@ def sample_token(logits: jax.Array, temperature: float,
 
 
 def make_decode_fn(cfg: Any, kernels: Optional[Dict[str, Any]] = None):
-    """Per-slot-length decode step: tokens [B,1], lengths [B].
-
-    Uses a vmapped length so slots at different fill levels share the
-    batch (the model's scalar-length path is the uniform-batch special
-    case used by the decode_* dry-run cells)."""
+    """Decode of every slot as one batch: tokens [B,1], lengths [B],
+    each slot at its own fill (the model's per-row-length path).  The
+    inner function's name names the program in a profiler trace
+    (``jit_decode_slots``)."""
 
     def decode_slots(params: PyTree, tokens: jax.Array, caches: PyTree,
                      lengths: jax.Array) -> Tuple[jax.Array, PyTree]:
-        def one(p, tok, cache, ln):
-            # vmap stripped the slot dim; re-add a batch dim of 1 at the
-            # per-leaf batch axis for the model's batched decode
-            axes = cache_batch_axes(cfg, cache)
-            cache_b = jax.tree.map(jnp.expand_dims, cache, axes)
-            lg, nc = decode_step(cfg, p, tok[None], cache_b, ln,
-                                 kernels=kernels)
-            nc = jax.tree.map(lambda t, a: jnp.squeeze(t, a), nc, axes)
-            return lg[0], nc
-
-        # vmap over the slot dimension (batch axis differs between
-        # prefix caches and scan-stacked caches)
-        cache_axes = cache_batch_axes(cfg, caches)
-        lg, new_caches = jax.vmap(
-            one, in_axes=(None, 0, cache_axes, 0),
-            out_axes=(0, cache_axes))(params, tokens, caches, lengths)
-        return lg, new_caches
+        return decode_step(cfg, params, tokens, caches, lengths,
+                           kernels=kernels)
 
     return decode_slots
 
@@ -149,7 +135,10 @@ class ServingEngine:
         self.finished: List[Request] = []
         self.failed: List[Request] = []
         self._key = jax.random.PRNGKey(scfg.seed)
-        self._decode = jax.jit(make_decode_fn(cfg, kernels))
+        # the cache is donated: the decode writes its new rows in place,
+        # and nothing else holds ``self.caches`` across the call
+        self._decode = jax.jit(make_decode_fn(cfg, kernels),
+                               donate_argnums=(2,))
         self._prefill_cache: Dict[int, Any] = {}
         self.stats = {"ticks": 0, "prefills": 0, "decoded_tokens": 0,
                       "evictions": 0}
@@ -300,7 +289,7 @@ class ServingEngine:
                                            self.caches, lengths)
             self._key, sub = jax.random.split(self._key)
             with TraceAnnotation("serve.sync", what="decode"):
-                nxt = np.asarray(sample_token(lg[:, 0] if lg.ndim == 3 else lg,
+                nxt = np.asarray(sample_token(lg[:, 0],
                                               self.scfg.temperature, sub))
             self.stats["ticks"] += 1
             for i in active:

@@ -10,6 +10,10 @@ from repro.models import apply_model, init_model
 from repro.serving import Request, ServeConfig, ServingEngine
 
 F32 = dict(dtype=jnp.float32, param_dtype=jnp.float32, q_block=8)
+HYBRID = dict(name="h", family="hybrid", n_layers=4, d_model=64, n_heads=4,
+              n_kv_heads=2, d_ff=128, vocab=97, attn_layer_period=4,
+              attn_layer_offset=1, ssm_state=16, ssm_head_dim=16,
+              ssm_chunk=8, **F32)
 
 
 def make(cfg):
@@ -31,12 +35,21 @@ def test_continuous_batching_drains(dense_setup):
     for i in range(7):
         eng.submit(Request(rid=i,
                            prompt=np.arange(4 + i % 3, dtype=np.int32)))
+    eng.tick()
+    donated = jax.tree.leaves(eng.caches)
     done = eng.run_until_drained()
     assert len(done) == 7
     assert all(len(r.output) == 6 for r in done)
     assert eng.stats["prefills"] == 7
     # slots were recycled: more requests than slots
     assert eng.stats["ticks"] >= 2
+    # the decode consumed the cache it was given; the engine's own stays
+    # usable: a request admitted into a freed slot after the drain gets
+    # the tokens the same prompt got before
+    assert all(x.is_deleted() for x in donated)
+    first = next(r for r in done if r.rid == 0)
+    eng.submit(Request(rid=7, prompt=first.prompt.copy()))
+    assert eng.run_until_drained()[-1].output == first.output
 
 
 def test_greedy_matches_full_forward(dense_setup):
@@ -55,10 +68,7 @@ def test_greedy_matches_full_forward(dense_setup):
 
 
 def test_hybrid_serving_greedy():
-    cfg = ModelConfig(name="h", family="hybrid", n_layers=4, d_model=64,
-                      n_heads=4, n_kv_heads=2, d_ff=128, vocab=97,
-                      attn_layer_period=4, attn_layer_offset=1,
-                      ssm_state=16, ssm_head_dim=16, ssm_chunk=8, **F32)
+    cfg = ModelConfig(**HYBRID)
     params = make(cfg)
     eng = ServingEngine(cfg, params, ServeConfig(n_slots=2, max_seq=64,
                                                  max_new_tokens=4))
@@ -71,6 +81,98 @@ def test_hybrid_serving_greedy():
                             jnp.asarray(toks, jnp.int32)[None])
         toks.append(int(jnp.argmax(lg[0, -1])))
     assert toks[len(r.prompt):] == r.output
+
+
+def _decode_families():
+    from repro.configs.base import get_smoke_config
+    return {
+        "dense": ModelConfig(name="d", n_layers=2, d_model=64, n_heads=4,
+                             n_kv_heads=2, d_ff=128, vocab=211, **F32),
+        "sliding_window": get_smoke_config("starcoder2-7b"),
+        "mla": get_smoke_config("deepseek-v3-671b"),
+        "hybrid": ModelConfig(**HYBRID),
+    }
+
+
+@pytest.mark.parametrize("family", ["dense", "sliding_window", "mla",
+                                    "hybrid"])
+def test_decode_slots_matches_per_slot_decode(family):
+    """The engine's one-batch decode with a length per slot gives every
+    slot the logits and cache that ``decode_step`` with that slot's
+    scalar length gives it alone: slots at 0, at max_seq - 2 and on
+    both sides of a block edge of the row write, and a free slot (length
+    0, stale cache)."""
+    from repro.models import decode_step
+    from repro.models.model import ROW_BLOCK, cache_batch_axes
+    cfg = _decode_families()[family]
+    params = make(cfg)
+    max_seq = 2 * ROW_BLOCK
+    lengths = np.array([0, max_seq - 2, ROW_BLOCK - 1, ROW_BLOCK, 0],
+                       np.int32)
+    eng = ServingEngine(cfg, params, ServeConfig(n_slots=len(lengths),
+                                                 max_seq=max_seq),
+                        use_executor=False)
+    leaves, tree = jax.tree.flatten(eng.caches)
+    keys = jax.random.split(jax.random.PRNGKey(3), len(leaves))
+    caches = tree.unflatten([jax.random.normal(k, x.shape, x.dtype)
+                             for k, x in zip(keys, leaves)])
+    axes = cache_batch_axes(cfg, caches)
+    tokens = jax.random.randint(jax.random.PRNGKey(4), (len(lengths), 1),
+                                0, cfg.vocab)
+    want = []
+    for b, n in enumerate(lengths):
+        one = jax.tree.map(lambda t, a: jnp.expand_dims(jnp.take(t, b, a), a),
+                           caches, axes)
+        want.append(decode_step(cfg, params, tokens[b:b + 1], one,
+                                jnp.int32(n)))
+    lg, got = eng._decode(params, tokens, caches, jnp.asarray(lengths))
+    for b, (lg_b, cache_b) in enumerate(want):
+        np.testing.assert_allclose(lg[b], lg_b[0], rtol=1e-5, atol=1e-5)
+        jax.tree.map(lambda g, w, a: np.testing.assert_allclose(
+            jnp.take(g, b, a), jnp.take(w, 0, a), rtol=1e-5, atol=1e-5),
+            got, cache_b, axes)
+
+
+def test_decode_slots_donates_and_moves_no_cache_sized_layout():
+    """The engine's decode donates every cache leaf, and no transpose,
+    broadcast or squeeze of its program touches an array as large as one
+    layer's K cache (the tied head's embedding transpose aside)."""
+    from repro.serving.engine import make_decode_fn
+    cfg = ModelConfig(name="d", n_layers=2, d_model=64, n_heads=4,
+                      n_kv_heads=2, d_ff=128, vocab=211,
+                      tie_embeddings=True, **F32)
+    params = make(cfg)
+    eng = ServingEngine(cfg, params, ServeConfig(n_slots=4, max_seq=64),
+                        use_executor=False)
+    tokens = jnp.zeros((4, 1), jnp.int32)
+    lengths = jnp.zeros((4,), jnp.int32)
+    text = eng._decode.lower(params, tokens, eng.caches, lengths).as_text()
+    n_cache = len(jax.tree.leaves(eng.caches))
+    assert text.count("tf.aliasing_output") \
+        + text.count("jax.buffer_donor") == n_cache
+    slab = 4 * 64 * cfg.n_kv_heads * cfg.head_dim
+    emb = params["embed"]["emb"].shape
+    jaxpr = jax.make_jaxpr(make_decode_fn(cfg))(params, tokens, eng.caches,
+                                                 lengths)
+
+    def eqns(jx):
+        for e in jx.eqns:
+            yield e
+            for v in e.params.values():
+                for sub in v if isinstance(v, (list, tuple)) else (v,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from eqns(sub)
+
+    moved = [(e.primitive.name, [v.aval.shape for v in e.invars])
+             for e in eqns(jaxpr.jaxpr)
+             if e.primitive.name in ("transpose", "broadcast_in_dim",
+                                     "squeeze")
+             and max(np.prod(v.aval.shape) for v in e.invars + e.outvars
+                     if hasattr(v.aval, "shape")) >= slab
+             and not (e.primitive.name == "transpose"
+                      and e.invars[0].aval.shape == emb)]
+    assert moved == []
 
 
 def test_eos_terminates(dense_setup):
