@@ -1,7 +1,12 @@
-"""Weights from a seed, and the plain float32 reference of the served
-decoder-only models (qwen2, starcoder2).
+"""The grouped-query-attention (GQA) dense decoder (qwen2, starcoder2):
+weights from a seed, the plain float32 reference, the map into the
+program's configuration and parameter tree, the operations and bytes
+its steps need, and its cut for CPU tests (``bench/archs/__init__.py``
+lists the names).
 
-Nothing here imports the system under test.  The weights are drawn in
+The reference imports nothing of the system under test; only
+``program_config`` and ``program_params`` name the program's types,
+to hand it the weights.  The weights are drawn in
 one jitted call from the seed, in the type they are served in; the
 harness hands them to the program, and the reference draws them again
 from the same seed after the program's state is freed.
@@ -32,6 +37,8 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from bench.flops import attention_flops
 
 HIGHEST = lax.Precision.HIGHEST
 NEG_INF = -1e30
@@ -253,3 +260,119 @@ def gaps_program(spec: Spec, fp8: bool = False):
     """The jitted ``gaps`` for one spec: (weights, tokens [S],
     targets [S]) -> [S]."""
     return jax.jit(lambda w, t, y: gaps(spec, w, t, y, fp8=fp8))
+
+
+# ---------------------------------------------------------------------------
+# the program's configuration and parameter tree
+# ---------------------------------------------------------------------------
+def program_config(spec: Spec, serve: Dict[str, Any]):
+    """The program's ``ModelConfig`` for the configuration file."""
+    from repro.configs.base import ModelConfig
+    dtype = jnp.dtype(spec.dtype)
+    return ModelConfig(
+        name=serve["name"], family="dense",
+        n_layers=spec.num_hidden_layers, d_model=spec.hidden_size,
+        n_heads=spec.num_attention_heads,
+        n_kv_heads=spec.num_key_value_heads, head_dim=spec.head_dim,
+        d_ff=spec.intermediate_size, vocab=spec.vocab_size,
+        norm=spec.norm, norm_eps=spec.norm_eps,
+        act="swiglu" if spec.gated_mlp else "gelu",
+        qkv_bias=spec.qkv_bias, rope_theta=spec.rope_theta,
+        sliding_window=spec.sliding_window,
+        tie_embeddings=spec.tie_word_embeddings,
+        max_seq_len=serve["max_seq"], dtype=dtype, param_dtype=dtype)
+
+
+def program_params(spec: Spec, w: Dict[str, Any]) -> Dict:
+    """The weights in the program's parameter tree (one scanned period
+    holding every layer)."""
+    def lin(name, bias=None):
+        p = {"w": w[name]}
+        if bias in w:
+            p["b"] = w[bias]
+        return p
+
+    def nrm(g, b):
+        return {"g": w[g], **({"b": w[b]} if b in w else {})}
+
+    ffn = ({"gate": lin("w_gate"), "up": lin("w_up"), "down": lin("w_down")}
+           if spec.gated_mlp else
+           {"fc1": lin("w_fc1", "b_fc1"), "fc2": lin("w_fc2", "b_fc2")})
+    layer = {"norm1": nrm("ln1_g", "ln1_b"),
+             "mixer": {"wq": lin("wq", "bq"), "wk": lin("wk", "bk"),
+                       "wv": lin("wv", "bv"), "wo": lin("wo")},
+             "norm2": nrm("ln2_g", "ln2_b"), "ffn": ffn}
+    params = {"embed": {"emb": w["emb"]}, "stack": {"l0": layer},
+              "final_norm": nrm("final_g", "final_b")}
+    if not spec.tie_word_embeddings:
+        params["head"] = {"w": w["head"]}
+    return params
+
+
+# ---------------------------------------------------------------------------
+# operations and bytes, from shapes (``bench/flops.py`` says what counts)
+# ---------------------------------------------------------------------------
+def _dims(spec: Spec) -> Dict[str, int]:
+    hd = spec.head_dim
+    return dict(d=spec.hidden_size, f=spec.intermediate_size,
+                hq=spec.num_attention_heads, hkv=spec.num_key_value_heads,
+                hd=hd, L=spec.num_hidden_layers, V=spec.vocab_size)
+
+
+def layer_matmul_params(spec: Spec) -> int:
+    """Weights one token multiplies through in one layer."""
+    k = _dims(spec)
+    attn = k["d"] * k["hq"] * k["hd"] * 2 + k["d"] * k["hkv"] * k["hd"] * 2
+    mlp = (3 if spec.gated_mlp else 2) * k["d"] * k["f"]
+    return attn + mlp
+
+
+def weight_count(spec: Spec) -> int:
+    """Every parameter of the served model (norms and biases included)."""
+    k = _dims(spec)
+    per_layer = layer_matmul_params(spec)
+    per_layer += (k["hq"] + 2 * k["hkv"]) * k["hd"] if spec.qkv_bias else 0
+    per_layer += (k["f"] + k["d"]) if spec.mlp_bias else 0
+    per_layer += 2 * k["d"] * (2 if spec.norm == "layer" else 1)
+    total = k["L"] * per_layer + k["V"] * k["d"]
+    total += k["d"] * (2 if spec.norm == "layer" else 1)
+    if not spec.tie_word_embeddings:
+        total += k["d"] * k["V"]
+    return total
+
+
+def kv_bytes_per_token(spec: Spec, itemsize: int = 2) -> int:
+    k = _dims(spec)
+    return k["L"] * 2 * k["hkv"] * k["hd"] * itemsize
+
+
+def prefill_flops(spec: Spec, s: int) -> int:
+    """One prompt of ``s`` tokens: every layer's matmuls and causal
+    attention, and the head for the last position only (the program
+    returns only the last position's logits)."""
+    k = _dims(spec)
+    mm = 2 * s * k["L"] * layer_matmul_params(spec)
+    att = k["L"] * attention_flops(k["hq"], k["hd"], s,
+                                   spec.sliding_window)
+    return mm + att + 2 * k["d"] * k["V"]
+
+
+def decode_bytes(spec: Spec, kv_rows: int, itemsize: int = 2) -> int:
+    """One decode step: every weight once, except the embedding rows
+    that an untied model only gathers, plus ``kv_rows`` cache rows
+    (summed over the slots it advances)."""
+    w = weight_count(spec)
+    if not spec.tie_word_embeddings:
+        w -= spec.vocab_size * spec.hidden_size
+    return w * itemsize + kv_rows * kv_bytes_per_token(spec, itemsize)
+
+
+# ---------------------------------------------------------------------------
+# the cut for CPU tests
+# ---------------------------------------------------------------------------
+def small(config: Dict[str, Any]) -> Dict[str, Any]:
+    """``config`` with every width, the depth and the vocabulary made
+    small; every mechanism it states kept."""
+    return dict(config, hidden_size=64, intermediate_size=128,
+                num_attention_heads=4, num_key_value_heads=2,
+                num_hidden_layers=2, vocab_size=256)

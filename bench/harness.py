@@ -36,7 +36,8 @@ def load_json(path: str) -> Any:
 class Cell:
     name: str
     chips: int
-    config: Dict[str, Any]        # bench/configs/<config>.json
+    config_file: str              # bench/configs/<config>.json
+    config: Dict[str, Any]        # what that file holds
     mix: Dict[str, Any]           # bench/traffic/<traffic>.json
     metrics: List[Dict[str, Any]]  # BENCHMARK.json entries it reports
 
@@ -59,11 +60,21 @@ def find_cell(workload: str, per_layer: bool,
                        f"known: {sorted(cells)}")
     w = cells[workload]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
-    return Cell(workload, int(w["chips"]),
+    return Cell(workload, int(w["chips"]), conf["file"],
                 load_json(os.path.join(ROOT, conf["file"])),
                 load_json(os.path.join(BENCH_DIR, "traffic",
                                        w["traffic"] + ".json")),
                 cell_metrics(bench, workload, per_layer))
+
+
+def by_kind(cell: Cell, table: Dict[str, Any]) -> Any:
+    """The entry of ``table`` for the ``"kind"`` that the cell's
+    configuration file states."""
+    kind = cell.config.get("kind")
+    if kind not in table:
+        raise ValueError(f"{cell.config_file}: \"kind\" is {kind!r}; "
+                         f"known: {sorted(table)}")
+    return table[kind]
 
 
 def load_reader(name: str) -> Callable[["Run"], Optional[float]]:
@@ -157,7 +168,8 @@ class Run:
     setup_s: float = 0.0
     window_t0: float = 0.0
     window_t1: float = 0.0
-    spec: Any = None              # modelref.Spec of a served model
+    spec: Any = None              # arch.Spec of a served model
+    arch: Any = None              # its module, bench/archs/<arch>.py
     requests: List[Req] = dataclasses.field(default_factory=list)
     ticks: List[Tick] = dataclasses.field(default_factory=list)
     rounds: int = 0

@@ -45,7 +45,8 @@ def execute(cell, devs, seed: int, seconds: float, trace: bool,
     tag = harness.device_tag(devs)
     harness.log(tag, f"cell {cell.name}, seed {seed}, {seconds}s, trace "
                      f"{int(trace)}; compilation cache {cache_dir}")
-    driver = serving if cell.config["kind"] == "causal_lm" else ring
+    driver = harness.by_kind(cell, {"causal_lm": serving,
+                                    "deployment": ring})
     out = driver.run_cell(cell, seed, seconds, trace, devs, limits, tag,
                           t_start, compiles)
     run, checks = out.run, out.checks

@@ -10,7 +10,8 @@ persistent cache) before it opens.  The window then drives
 at the end of the tick that produced them.
 
 ``correct`` compares the tokens the window served with the float32
-reference (``modelref``): a sample of finished requests, drawn from the
+reference of the configuration's architecture (``bench/archs/<arch>.py``,
+named by its ``"arch"``): a sample of finished requests, drawn from the
 seed and always holding the longest, is run through the reference once
 the engine is freed, and the widest gap by which a served token's
 reference logit lies below the reference's best must stay under the
@@ -20,12 +21,14 @@ also finish with all the tokens it asked for.
 from __future__ import annotations
 
 import gc
+import importlib
+import os
 import time
 from typing import Any, Dict, List, Tuple
 
 import numpy as np
 
-from bench import harness, loadgen, modelref
+from bench import archs, harness, loadgen
 from bench.harness import Req, Run, Tick, now, span
 
 # weights drawn from the seed get this stream; the sample of requests
@@ -33,49 +36,28 @@ from bench.harness import Req, Run, Tick, now, span
 CHECK_STREAM = 0x5EED
 
 
-def program_config(spec: modelref.Spec, serve: Dict[str, Any]):
-    """The program's ``ModelConfig`` for the configuration file."""
-    import jax.numpy as jnp
-    from repro.configs.base import ModelConfig
-    dtype = jnp.dtype(spec.dtype)
-    return ModelConfig(
-        name=serve["name"], family="dense",
-        n_layers=spec.num_hidden_layers, d_model=spec.hidden_size,
-        n_heads=spec.num_attention_heads,
-        n_kv_heads=spec.num_key_value_heads, head_dim=spec.head_dim,
-        d_ff=spec.intermediate_size, vocab=spec.vocab_size,
-        norm=spec.norm, norm_eps=spec.norm_eps,
-        act="swiglu" if spec.gated_mlp else "gelu",
-        qkv_bias=spec.qkv_bias, rope_theta=spec.rope_theta,
-        sliding_window=spec.sliding_window,
-        tie_embeddings=spec.tie_word_embeddings,
-        max_seq_len=serve["max_seq"], dtype=dtype, param_dtype=dtype)
+def load_arch(config: Dict[str, Any], source: str):
+    """(the module ``bench/archs/<arch>.py`` that the configuration's
+    ``"arch"`` names, its ``Spec`` of the configuration); ``source`` is
+    the configuration's file, named in the error when there is none."""
+    name = config.get("arch")
+    known = sorted(f[:-3] for f in os.listdir(os.path.dirname(
+        archs.__file__)) if f.endswith(".py") and f != "__init__.py")
+    if name not in known:
+        raise ValueError(f"{source}: \"arch\" is {name!r}; it has to name "
+                         f"a module of bench/archs/: {known}")
+    arch = importlib.import_module(f"{archs.__name__}.{name}")
+    return arch, arch.Spec.from_config(config)
 
 
-def program_params(spec: modelref.Spec, w: Dict[str, Any]) -> Dict:
-    """The weights in the program's parameter tree (one scanned period
-    holding every layer)."""
-    def lin(name, bias=None):
-        p = {"w": w[name]}
-        if bias in w:
-            p["b"] = w[bias]
-        return p
-
-    def nrm(g, b):
-        return {"g": w[g], **({"b": w[b]} if b in w else {})}
-
-    ffn = ({"gate": lin("w_gate"), "up": lin("w_up"), "down": lin("w_down")}
-           if spec.gated_mlp else
-           {"fc1": lin("w_fc1", "b_fc1"), "fc2": lin("w_fc2", "b_fc2")})
-    layer = {"norm1": nrm("ln1_g", "ln1_b"),
-             "mixer": {"wq": lin("wq", "bq"), "wk": lin("wk", "bk"),
-                       "wv": lin("wv", "bv"), "wo": lin("wo")},
-             "norm2": nrm("ln2_g", "ln2_b"), "ffn": ffn}
-    params = {"embed": {"emb": w["emb"]}, "stack": {"l0": layer},
-              "final_norm": nrm("final_g", "final_b")}
-    if not spec.tie_word_embeddings:
-        params["head"] = {"w": w["head"]}
-    return params
+def load_model(config: Dict[str, Any], source: str, seed: int):
+    """(arch, spec, the program's ``ModelConfig``, its parameters holding
+    the weights drawn from ``seed``) for a served configuration."""
+    arch, spec = load_arch(config, source)
+    cfg = arch.program_config(spec, config["serve"])
+    params = arch.program_params(spec, arch.make_weights(spec, seed))
+    check_layout(cfg, params)
+    return arch, spec, cfg, params
 
 
 def check_layout(cfg, params) -> None:
@@ -243,13 +225,13 @@ def sample_checked(run: Run, outputs: Dict[int, Tuple[np.ndarray, List[int]]],
     return picked
 
 
-def widest_gap(spec: modelref.Spec, seed: int, max_seq: int,
+def widest_gap(arch, spec, seed: int, max_seq: int,
                checked: List[Tuple[np.ndarray, List[int]]],
                fp8: bool = False) -> Tuple[float, int]:
     """(widest gap over every served token checked, tokens checked)."""
     import jax.numpy as jnp
-    w = modelref.make_weights(spec, seed)
-    fn = modelref.gaps_program(spec, fp8=fp8)
+    w = arch.make_weights(spec, seed)
+    fn = arch.gaps_program(spec, fp8=fp8)
     widest, count = 0.0, 0
     for prompt, out in checked:
         seq = np.concatenate([prompt, np.asarray(out[:-1], np.int32)])
@@ -268,22 +250,19 @@ def run_cell(cell: harness.Cell, seed: int, seconds: float, trace: bool,
              devs, limits: Dict[str, float], tag: str, t_start: float,
              compiles: harness.CompileLog) -> harness.Outcome:
     """One run of a serving cell: set-up, window, check."""
-    spec = modelref.Spec.from_config(cell.config)
     serve, mix = cell.config["serve"], cell.mix
     if loadgen.longest(mix) > serve["max_seq"] - 1:
         raise ValueError(f"the mix fills {loadgen.longest(mix)} positions, "
                          f"max_seq is {serve['max_seq']}")
+    arch, spec, cfg, params = load_model(cell.config, cell.config_file, seed)
     run = Run(cell.name, cell.config, mix, devs[0].device_kind, len(devs),
-              spec=spec)
+              spec=spec, arch=arch)
     if mix["kind"] == "open_loop":
         planned = loadgen.open_loop(mix, seconds, seed, spec.vocab_size)
     else:
         planned = loadgen.closed_loop(mix, seed, spec.vocab_size)
     harness.log(tag, "traffic: " + loadgen.describe(planned))
 
-    cfg = program_config(spec, serve)
-    params = program_params(spec, modelref.make_weights(spec, seed))
-    check_layout(cfg, params)
     drv = Driver(build(cfg, params, serve, max(p.max_new for p in planned),
                        seed, None), run)
     del params
@@ -323,7 +302,7 @@ def run_cell(cell: harness.Cell, seed: int, seconds: float, trace: bool,
                             mix["check_tokens"])
     t = now()
     checked = [served[i] for i in picked]
-    gap, n_tok = widest_gap(spec, seed, serve["max_seq"], checked)
+    gap, n_tok = widest_gap(arch, spec, seed, serve["max_seq"], checked)
     harness.log(tag, f"reference: {len(picked)} requests, {n_tok} served "
                      f"tokens checked in {now() - t:.3f}s")
     checks = {"logit_gap": {"value": gap, "limit": limits["logit_gap"]},

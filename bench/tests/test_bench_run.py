@@ -98,6 +98,80 @@ def test_altered_token_is_caught(no_cache, monkeypatch, workload):
         res["checks"]["logit_gap"]["limit"]
 
 
+@pytest.mark.parametrize("fault", ["no arch", "unknown arch",
+                                   "unknown kind"])
+def test_config_without_a_driver_is_refused_by_its_file(no_cache, fault):
+    """A served configuration that names no architecture, or one that
+    ``bench/archs/`` does not hold, or a kind no driver serves."""
+    cell = tiny.serving_cell("qwen2-0.5b", "chat")
+    if fault == "no arch":
+        del cell.config["arch"]
+    elif fault == "unknown arch":
+        cell.config["arch"] = "gqa2"
+    else:
+        cell.config["kind"] = "causal-lm"
+    with pytest.raises(ValueError) as e:
+        execute(cell, jax.devices()[:1], 1, 1.5, False, {"logit_gap": 0.05},
+                time.perf_counter())
+    assert cell.config_file == "bench/configs/qwen2-0.5b.json"
+    assert cell.config_file in str(e.value)
+    if fault != "unknown kind":
+        assert "bench/archs/" in str(e.value)
+
+
+PROBE = '''"""GQA under another name, with a cut of its own for CPU tests."""
+from bench.archs import gqa
+from bench.archs.gqa import *  # noqa: F401,F403
+
+
+def small(config):
+    return dict(gqa.small(config), num_hidden_layers=1)
+'''
+
+PLUGIN = """
+import json, sys
+sys.path[:0] = [{root!r}, {src!r}]
+from bench import serving
+from bench.tests import tiny
+from bench.tests.test_bench_run import serve_once
+cell = tiny.serving_cell("probe-0.5b", "chat")
+arch, _ = serving.load_arch(cell.config, cell.config_file)
+print(json.dumps({{"arch_file": arch.__file__,
+                  "layers": cell.config["num_hidden_layers"]}}))
+print(json.dumps(serve_once(("probe-0.5b", "chat"), 2**33 + 21)))
+"""
+
+
+def test_a_new_architecture_needs_only_new_files(tmp_path):
+    """A copy of the benchmark, with one architecture module and one
+    configuration that names it added and nothing else changed, serves
+    a small cell correctly."""
+    shutil.copy(os.path.join(harness.ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(harness.BENCH_DIR, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (tmp_path / "bench" / "archs" / "probe.py").write_text(PROBE)
+    conf = harness.load_json(os.path.join(harness.BENCH_DIR, "configs",
+                                          "qwen2-0.5b.json"))
+    conf.update(arch="probe", serve=dict(conf["serve"], name="probe-0.5b"))
+    (tmp_path / "bench" / "configs" / "probe-0.5b.json").write_text(
+        json.dumps(conf))
+    script = tmp_path / "plugin.py"
+    script.write_text(PLUGIN.format(root=str(tmp_path),
+                                    src=os.path.join(harness.ROOT, "src")))
+    p = subprocess.run(
+        [sys.executable, str(script)], cwd=tmp_path,
+        env=cpu_env(JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache")),
+        capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-3000:]
+    lines = [json.loads(l) for l in p.stdout.splitlines()
+             if l.startswith("{")]
+    assert lines[0] == {"arch_file": str(tmp_path / "bench" / "archs" /
+                                         "probe.py"), "layers": 1}
+    res = lines[-1]
+    assert res["correct"] is True and res["failed"] == 0
+    assert res["checks"]["logit_gap"]["value"] <= 0.05
+
+
 RING = """
 import json, sys, time
 sys.path[:0] = [{root!r}, {src!r}]
@@ -110,7 +184,7 @@ if sys.argv[1] == "broken":
     shift = lcx.Perm.shift
     lcx.Perm.shift = staticmethod(lambda k: shift(0))
 cell = harness.Cell(
-    "lcx-ring4.pingpong", 4,
+    "lcx-ring4.pingpong", 4, "bench/configs/lcx-ring4.json",
     harness.load_json(harness.BENCH_DIR + "/configs/lcx-ring4.json"),
     harness.load_json(harness.BENCH_DIR + "/traffic/pingpong.json"),
     [{{"name": "msg_rate", "unit": "msgs/s"}}])

@@ -2,7 +2,7 @@
 by hand, and on one recorded on the CPU."""
 import pytest
 
-from bench import flops, harness, modelref, tracing
+from bench import flops, harness, serving, tracing
 from bench.harness import Run, Tick
 from bench.tracing import Device
 
@@ -62,26 +62,30 @@ def test_busy_share_averages_over_chips():
         pytest.approx(50.0)
 
 
-def spec():
-    return modelref.Spec.from_config(harness.load_json(
-        f"{harness.BENCH_DIR}/configs/qwen2-0.5b.json"))
+def load(config="qwen2-0.5b"):
+    """(arch, spec) of a served configuration file, as the harness loads
+    them."""
+    path = f"bench/configs/{config}.json"
+    return serving.load_arch(
+        harness.load_json(f"{harness.ROOT}/{path}"), path)
 
 
 def test_device_readers_on_a_made_trace():
-    sp = spec()
+    arch, sp = load()
     dev = Device(modules=[("jit_prefill_slot", 0.0, 10 * MS),
                           ("jit_decode_slots", 20 * MS, 30 * MS)],
                  ops=[("flash_attention", 1 * MS, 2 * MS)])
-    run = Run("w", {}, {"kind": "open_loop"}, "TPU v5 lite", 1, spec=sp)
+    run = Run("w", {}, {"kind": "open_loop"}, "TPU v5 lite", 1, spec=sp,
+              arch=arch)
     run.trace = trace_of({"/device:TPU:0": dev}, [])
     run.ticks = [Tick(0.0, 0.031, [512], 3, 1500, traced=True),
                  Tick(0.031, 0.05, [384], 3, 1500, traced=False)]
     pk = flops.PEAKS["TPU v5 lite"]
     r = harness.load_reader
     assert r("prefill_mfu_pct")(run) == pytest.approx(
-        100 * flops.prefill_flops(sp, 512) / (0.01 * pk["bf16_flops"]))
+        100 * arch.prefill_flops(sp, 512) / (0.01 * pk["bf16_flops"]))
     assert r("decode_hbm_pct")(run) == pytest.approx(
-        100 * flops.decode_bytes(sp, 1500) / (0.01 * pk["hbm_bytes_per_s"]))
+        100 * arch.decode_bytes(sp, 1500) / (0.01 * pk["hbm_bytes_per_s"]))
     f, b = flops.flash_cost(14, 2, 64, 512)
     assert r("flash_roofline")(run) == pytest.approx(
         100 * 24 * max(f / pk["bf16_flops"], b / pk["hbm_bytes_per_s"])
@@ -105,19 +109,44 @@ def test_permute_per_round():
         pytest.approx(30.0)
 
 
-def test_flop_counts_from_shapes():
-    sp = spec()
-    # qwen2-0.5b: 24 layers of (896x896 q, o + 2 x 896x128 k, v +
-    # 3 x 896x4864 mlp) weights per token
-    per_layer = 2 * 896 * 896 + 2 * 896 * 128 + 3 * 896 * 4864
-    assert flops.layer_matmul_params(sp) == per_layer
-    assert flops.prefill_flops(sp, 1) == 2 * 24 * per_layer + \
-        24 * 4 * 14 * 64 + 2 * 896 * 151936
+# per layer, the weights one token multiplies through: q and o, k and v,
+# and the MLP (three matrices gated, two not)
+QWEN2_LAYER = 2 * 896 * 896 + 2 * 896 * 128 + 3 * 896 * 4864
+STARCODER2_LAYER = 2 * 4608 * 4608 + 2 * 4608 * 512 + 2 * 4608 * 18432
+
+
+# the counts of each served configuration, pinned to what they were when
+# they were kept in bench/flops.py: per-layer readers divide by them
+@pytest.mark.parametrize("config,pins", [
+    ("qwen2-0.5b", dict(
+        layer_matmul_params=QWEN2_LAYER,
+        # one position: 24 layers, 14 heads of 64 at one pair, the head
+        prefill_1=2 * 24 * QWEN2_LAYER + 24 * 4 * 14 * 64 + 2 * 896 * 151936,
+        # 494M parameters, the published count of Qwen2-0.5B
+        weight_count=494032768, kv_bytes_per_token=12288,
+        decode_0=988065536, decode_1500=1006497536,
+        prefill_384=281441370112, prefill_1536=1201050124288)),
+    ("starcoder2-7b", dict(
+        layer_matmul_params=STARCODER2_LAYER,
+        prefill_1=2 * 8 * STARCODER2_LAYER + 8 * 4 * 36 * 128
+        + 2 * 4608 * 49152,
+        weight_count=2189812736, kv_bytes_per_token=16384,
+        decode_0=3926640640, decode_1500=3951216640,
+        prefill_384=1344940277760, prefill_1536=5508861788160)),
+], ids=["qwen2-0.5b", "starcoder2-7b"])
+def test_flop_counts_from_shapes(config, pins):
+    arch, sp = load(config)
+    assert arch.layer_matmul_params(sp) == pins["layer_matmul_params"]
+    assert arch.prefill_flops(sp, 1) == pins["prefill_1"]
+    assert arch.weight_count(sp) == pins["weight_count"]
+    assert arch.kv_bytes_per_token(sp) == pins["kv_bytes_per_token"]
+    assert arch.decode_bytes(sp, 0) == pins["decode_0"]
+    assert arch.decode_bytes(sp, 1500) == pins["decode_1500"]
+    assert arch.prefill_flops(sp, 384) == pins["prefill_384"]
+    assert arch.prefill_flops(sp, 1536) == pins["prefill_1536"]
     assert flops.attention_flops(1, 1, 4) == 4 * 10
     assert flops.attention_flops(1, 1, 4, window=2) == 4 * 7
-    assert flops.kv_bytes_per_token(sp) == 12288
-    # 494M parameters, the published count of Qwen2-0.5B
-    assert 490e6 < flops.weight_count(sp) < 500e6
+    assert flops.flash_cost(14, 2, 64, 512) == (470679552, 2097152)
     with pytest.raises(KeyError):
         flops.peaks("cpu")
 

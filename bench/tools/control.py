@@ -31,11 +31,11 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 
 def served_row(cell, seed, seconds, devs, limits, tag, compiles):
-    from bench import harness, modelref, serving
+    from bench import harness, serving
     out = serving.run_cell(cell, seed, seconds, False, devs, limits, tag,
                            time.perf_counter(), compiles)
-    ctrl, n = serving.widest_gap(modelref.Spec.from_config(cell.config),
-                                 seed, cell.config["serve"]["max_seq"],
+    ctrl, n = serving.widest_gap(out.run.arch, out.run.spec, seed,
+                                 cell.config["serve"]["max_seq"],
                                  out.checked, fp8=True)
     judged = dict(out.checks, logit_gap={"value": ctrl,
                                          "limit": limits["logit_gap"]})
@@ -84,7 +84,8 @@ def main() -> int:
     tag = harness.device_tag(devs)
     limits = harness.load_json(os.path.join(harness.BENCH_DIR, "limits",
                                             cell.name + ".json"))
-    row_of = served_row if cell.config["kind"] == "causal_lm" else ring_row
+    row_of = harness.by_kind(cell, {"causal_lm": served_row,
+                                    "deployment": ring_row})
     rows = []
     for seed in [int(s) for s in args.seeds.split(",")]:
         row = row_of(cell, seed, args.seconds, devs, limits, tag, compiles)

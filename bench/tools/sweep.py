@@ -59,18 +59,16 @@ def main() -> int:
     args = p.parse_args()
 
     import jax
-    from bench import harness, loadgen, modelref, serving
+    from bench import harness, loadgen, serving
     from repro.launch.compile_cache import enable_compilation_cache
     cell = harness.find_cell(args.workload, False)
     devs = harness.chips(cell.chips)
     enable_compilation_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     tag = harness.device_tag(devs)
-    spec = modelref.Spec.from_config(cell.config)
+    arch, spec, cfg, params = serving.load_model(
+        cell.config, cell.config_file, args.seed)
     serve, mix = cell.config["serve"], dict(cell.mix, drain_s=args.drain)
-    cfg = serving.program_config(spec, serve)
-    params = serving.program_params(spec,
-                                    modelref.make_weights(spec, args.seed))
     eng = serving.build(cfg, params, serve, mix["output"]["max"], args.seed,
                         None)
     pr = mix["prompt"]
@@ -85,7 +83,7 @@ def main() -> int:
         planned = loadgen.open_loop(mx, args.seconds, args.seed,
                                     spec.vocab_size)
         run = harness.Run(cell.name, cell.config, mx, devs[0].device_kind,
-                          len(devs), spec=spec)
+                          len(devs), spec=spec, arch=arch)
         drv = serving.Driver(eng, run)
         serving.window(drv, mx, planned, args.seconds,
                        harness.Tracer(False, 0, 0))
