@@ -2,6 +2,8 @@
 look for a chip, driven on the CPU at a small size with the timed path
 sound and then broken underneath, where ``correct`` must come out
 false."""
+import ast
+import inspect
 import json
 import os
 import re
@@ -13,7 +15,7 @@ import time
 import jax
 import pytest
 
-from bench import harness
+from bench import harness, ring, serving
 from bench.run import execute
 from bench.tests import tiny
 
@@ -183,6 +185,11 @@ if sys.argv[1] == "broken":
     # the exchange between chips left out: every put goes to itself
     shift = lcx.Perm.shift
     lcx.Perm.shift = staticmethod(lambda k: shift(0))
+elif sys.argv[1] == "altered":
+    # a payload altered where the exchange produces it
+    from repro.core import ops
+    permute = ops._permute
+    ops._permute = lambda value, dev, perm: permute(value, dev, perm) ^ 1
 cell = harness.Cell(
     "lcx-ring4.pingpong", 4, "bench/configs/lcx-ring4.json",
     harness.load_json(harness.BENCH_DIR + "/configs/lcx-ring4.json"),
@@ -193,7 +200,7 @@ print(execute(cell, jax.devices()[:4], 2**34 + 9, 1.0, False,
 """
 
 
-@pytest.mark.parametrize("mode", ["sound", "broken"])
+@pytest.mark.parametrize("mode", ["sound", "broken", "altered"])
 def test_ring_exchange_left_out_is_caught(tmp_path, mode):
     script = tmp_path / "ring.py"
     script.write_text(RING.format(root=harness.ROOT,
@@ -242,3 +249,33 @@ def test_every_entry_is_found_by_name():
         for w in m["workloads"]:
             reported = harness.cell_metrics(b, w, False)
             assert m["moves"] in {x["name"] for x in reported}
+
+
+BENCH = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
+# the module that runs each kind of cell, as bench/run.py picks it, and
+# the checks it compares with a limit from the cell's limits file
+RUNS = {"causal_lm": serving, "deployment": ring}
+CHECKS = {"causal_lm": {"logit_gap"}, "deployment": {"wrong_payloads"}}
+
+
+def limits_read(module) -> set:
+    """The keys that ``module.run_cell`` reads from its limits."""
+    tree = ast.parse(inspect.getsource(module.run_cell))
+    return {n.slice.value for n in ast.walk(tree)
+            if isinstance(n, ast.Subscript) and isinstance(n.value, ast.Name)
+            and n.value.id == "limits" and isinstance(n.slice, ast.Constant)}
+
+
+@pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
+def test_limits_name_the_checks_of_their_kind(workload):
+    """A cell's limits file holds a number for each check that the
+    module running its kind compares with a limit from the file, and
+    nothing else."""
+    cell = harness.find_cell(workload, False, BENCH)
+    kind = cell.config["kind"]
+    limits = harness.load_json(os.path.join(harness.BENCH_DIR, "limits",
+                                            workload + ".json"))
+    assert limits_read(harness.by_kind(cell, RUNS)) == CHECKS[kind]
+    assert set(limits) == CHECKS[kind]
+    assert all(isinstance(v, (int, float)) and v >= 0
+               for v in limits.values())
