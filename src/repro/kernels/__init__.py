@@ -7,8 +7,8 @@ compiled Mosaic on real TPUs.  The ring all-gather is the LCX
 put-with-remote-signal pattern at the DMA level.
 """
 from . import ops, ref
-from .ops import (flash_attention, model_kernels, moe_gmm, on_tpu,
-                  ring_all_gather, ssd_scan)
+from .ops import (flash_attention, kv_row_write, model_kernels, moe_gmm,
+                  on_tpu, ring_all_gather, ssd_scan)
 
-__all__ = ["ops", "ref", "flash_attention", "model_kernels", "moe_gmm",
-           "on_tpu", "ring_all_gather", "ssd_scan"]
+__all__ = ["ops", "ref", "flash_attention", "kv_row_write", "model_kernels",
+           "moe_gmm", "on_tpu", "ring_all_gather", "ssd_scan"]

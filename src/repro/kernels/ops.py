@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from . import ref as _ref
 from .flash_attention import flash_attention as _flash_pallas
+from .kv_row_write import kv_row_write as _row_write_pallas
 from .moe_gmm import moe_gmm as _gmm_pallas
 from .ring_allgather import ring_all_gather as _ring_pallas
 
@@ -120,6 +121,20 @@ def ring_all_gather(x: jax.Array, axis: str, *, axis_size: int,
 
 
 # ---------------------------------------------------------------------------
+# KV row write (one new row per slot, in place)
+# ---------------------------------------------------------------------------
+def kv_row_write(pool: jax.Array, rows: jax.Array, lengths: jax.Array, *,
+                 backend: Optional[str] = None) -> jax.Array:
+    """pool [L,B,S,W], rows [L,B,W], lengths [B] -> pool with
+    ``pool[:, b, lengths[b]] = rows[:, b]``."""
+    be = _resolve(backend)
+    if be == "xla":
+        return _ref.kv_row_write_ref(pool, rows, lengths)
+    return _row_write_pallas(pool, rows, lengths,
+                             interpret=(be == "interpret"))
+
+
+# ---------------------------------------------------------------------------
 # model hook adapters
 # ---------------------------------------------------------------------------
 def model_kernels(cfg: Any, backend: Optional[str] = None
@@ -131,6 +146,11 @@ def model_kernels(cfg: Any, backend: Optional[str] = None
       sliding-window configs: the kernel has no window, and the model's
       own attention applies it.
     - ssd_scan hook signature: (x,dt,A,B,C, chunk) -> (y, h_final)
+    - kv_row_write hook signature: (pool [L,B,S,W], rows [L,B,W],
+      lengths [B]) -> pool; the per-row decode hands it each cache leaf
+      that fits it.  Left out on the ``xla`` backend: there the model's
+      own block write stays, since XLA lays a TPU pool out anew around a
+      scatter into it.
     """
     backend = _resolve(backend)
 
@@ -146,7 +166,12 @@ def model_kernels(cfg: Any, backend: Optional[str] = None
     def ssd_hook(x, dt, A, Bm, Cm, *, chunk):
         return ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, backend=backend)
 
+    def row_write_hook(pool, rows, lengths):
+        return kv_row_write(pool, rows, lengths, backend=backend)
+
     kernels: Dict[str, Any] = {"ssd_scan": ssd_hook}
     if cfg.sliding_window is None:
         kernels["flash_attention"] = attn_hook
+    if backend != "xla":
+        kernels["kv_row_write"] = row_write_hook
     return kernels

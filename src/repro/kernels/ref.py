@@ -65,3 +65,11 @@ def moe_gmm_ref(xb: jax.Array, w: jax.Array) -> jax.Array:
 def ring_allgather_ref(x: jax.Array, axis: str) -> jax.Array:
     """Under shard_map: x [1, ...] per device -> [n, ...]."""
     return lax.all_gather(x[0], axis, tiled=False)
+
+
+def kv_row_write_ref(pool: jax.Array, rows: jax.Array,
+                     lengths: jax.Array) -> jax.Array:
+    """pool [L,B,S,W] with ``pool[:, b, lengths[b]] = rows[:, b]``; a
+    length outside [0, S) writes nothing."""
+    b = jnp.arange(pool.shape[1])
+    return pool.at[:, b, lengths].set(rows.astype(pool.dtype), mode="drop")

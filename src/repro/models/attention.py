@@ -24,7 +24,11 @@ inner loop on real TPUs via ``kernels={"flash_attention": ...}``; the
 dry-run uses this pure-XLA path (CPU placeholder devices cannot compile
 Mosaic kernels).
 
-Decode uses a pre-allocated KV cache ``{k, v: [B, S_max, n_kv, hd]}``.
+Decode uses a pre-allocated KV cache ``{k, v: [B, S_max, n_kv · hd]}``:
+each position's heads sit side by side in one row, so a row is whole
+lanes on a TPU and the pool keeps its row-major layout through a
+per-row write.  The decode reads it in that layout, each row whole
+(``_row_scores``, ``_row_values``).
 """
 from __future__ import annotations
 
@@ -111,12 +115,41 @@ def _gqa_out(p: jax.Array, v: jax.Array) -> jax.Array:
     return out.reshape(b, qlen, hkv * g, v.shape[-1])
 
 
+def _row_scores(q: jax.Array, buf: jax.Array, n_kv: int) -> jax.Array:
+    """q [B,1,Hq,hd] against every row of a cache leaf [B,S,Hkv·hd] ->
+    grouped scores [B,Hkv,G,1,S] (f32).  Each query head is spread over
+    the whole row, zero outside its own kv head's part, so the leaf is
+    contracted over its row in its own layout: a [B,S,Hkv,hd] view of it
+    would make XLA lay a TPU pool out anew in every layer."""
+    b, _, hq, hd = q.shape
+    g = hq // n_kv
+    eye = jnp.eye(n_kv, dtype=q.dtype)
+    qw = (q.reshape(b, n_kv, g, 1, hd) * eye[:, None, :, None]
+          ).reshape(b, hq, n_kv * hd)
+    with jax.named_scope("kv_read"):
+        s = jnp.einsum("bnw,bsw->bns", qw, buf,
+                       preferred_element_type=jnp.float32)
+    return s.reshape(b, n_kv, g, 1, -1)
+
+
+def _row_values(p: jax.Array, buf: jax.Array) -> jax.Array:
+    """Weights p [B,Hkv,G,1,S] over the rows of a cache leaf [B,S,Hkv·hd]
+    -> [B,Hkv,G,1,hd] (f32): each head sums whole rows, of which it keeps
+    its own kv head's part (``_row_scores``'s layout)."""
+    b, n_kv, g, _, s = p.shape
+    with jax.named_scope("kv_read"):
+        o = jnp.einsum("bns,bsw->bnw", p.reshape(b, n_kv * g, s), buf,
+                       preferred_element_type=jnp.float32)
+    o = o.reshape(b, n_kv, g, n_kv, -1)
+    eye = jnp.eye(n_kv, dtype=o.dtype)
+    return (o * eye[:, None, :, None]).sum(axis=3)[:, :, :, None]
+
+
 def attention_full(q: jax.Array, k: jax.Array, v: jax.Array, *,
                    scale: float, causal: bool, window: Optional[int],
-                   q_pos: jax.Array, k_pos: jax.Array,
-                   k_valid: Optional[jax.Array] = None) -> jax.Array:
+                   q_pos: jax.Array, k_pos: jax.Array) -> jax.Array:
     s = _gqa_scores(q, k) * scale
-    s = s + _mask_bias(q_pos, k_pos, causal, window, k_valid)
+    s = s + _mask_bias(q_pos, k_pos, causal, window)
     p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     return _gqa_out(p, v)
 
@@ -510,7 +543,7 @@ def attn_decode_sharded(cfg: Any, q: jax.Array, k_new: jax.Array,
     b = q.shape[0]
     scale = 1.0 / math.sqrt(cfg.head_dim)
     bspec = _dp_prefix(mesh, b)
-    cspec = P(bspec, "model", None, None)
+    cspec = P(bspec, "model", None)
     qspec = P(bspec, None, None, None)
 
     def body(q_, kn, vn, ck, cv, ln):
@@ -519,16 +552,17 @@ def attn_decode_sharded(cfg: Any, q: jax.Array, k_new: jax.Array,
         start = rank * s_loc
         off = ln - start
         in_range = (off >= 0) & (off < s_loc)
-        ck = _local_row_update(ck, kn, off, in_range)
-        cv = _local_row_update(cv, vn, off, in_range)
-        s = _gqa_scores(q_, ck.astype(q_.dtype)) * scale  # [B,Hkv,G,1,Sl]
+        ck = _local_row_update(ck, kn.reshape(ck.shape[0], 1, -1), off,
+                               in_range)
+        cv = _local_row_update(cv, vn.reshape(cv.shape[0], 1, -1), off,
+                               in_range)
+        s = _row_scores(q_, ck.astype(q_.dtype), cfg.n_kv_heads) * scale
         pos = start + jnp.arange(s_loc)
         s = jnp.where((pos <= ln)[None, None, None, None, :], s, NEG_INF)
         m = s.max(axis=-1)
         p = jnp.exp(s - m[..., None])
         l = p.sum(axis=-1)
-        acc = jnp.einsum("bhgqk,bkhd->bhgqd", p.astype(cv.dtype),
-                         cv.astype(q_.dtype))
+        acc = _row_values(p.astype(cv.dtype), cv.astype(q_.dtype))
         out = _flash_decode_combine(acc, m, l, "model")
         return out.astype(q_.dtype), ck, cv
 
@@ -545,14 +579,18 @@ def attn_decode_sharded(cfg: Any, q: jax.Array, k_new: jax.Array,
 
 def attn_cache_init(cfg: Any, batch: int, max_seq: int,
                     dtype: Any = None) -> PyTree:
+    """{k, v: [batch, max_seq, n_kv_heads · head_dim]}."""
     dtype = dtype or cfg.dtype
-    shape = (batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
+    shape = (batch, max_seq, cfg.n_kv_heads * cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def attn_cache_dims() -> PyTree:
-    return {"k": ("cache_batch", "cache_seq", "kv_heads", "head"),
-            "v": ("cache_batch", "cache_seq", "kv_heads", "head")}
+    # a row holds the kv heads side by side: split over "kv_heads" it
+    # keeps whole heads together where the head count divides the axis,
+    # and decode_rules replicate it where it does not
+    return {"k": ("cache_batch", "cache_seq", "kv_heads"),
+            "v": ("cache_batch", "cache_seq", "kv_heads")}
 
 
 def old_rows_mask(cache_seq: int, length: jax.Array,
@@ -577,30 +615,30 @@ def _attn_decode_rows(cfg: Any, p: PyTree, x: jax.Array, cache: PyTree,
     """``attn_decode`` with a length per row [B].  The cache is only
     read: each row attends its old rows ``< length[b]`` and its new K/V
     row, one extra logit and value term of the same softmax.  Returns
-    (y, the new row {k, v: [B,Hkv,hd]})."""
+    (y, the new row {k, v: [B, Hkv·hd]})."""
     b = x.shape[0]
     ok = old_rows_mask(cache["k"].shape[1], length, cfg.sliding_window)
     q, k_new, v_new = _project_qkv(cfg, p, x, length[:, None])
     scale = 1.0 / math.sqrt(cfg.head_dim)
-    s_old = _gqa_scores(q, cache["k"].astype(x.dtype)) * scale
+    s_old = _row_scores(q, cache["k"].astype(x.dtype), cfg.n_kv_heads) \
+        * scale
     s_old = jnp.where(ok[:, None, None, None, :], s_old, NEG_INF)
     s_new = _gqa_scores(q, k_new) * scale               # [B,Hkv,G,1,1]
     m = jnp.maximum(s_old.max(axis=-1, keepdims=True), s_new)
     p_old, p_new = jnp.exp(s_old - m), jnp.exp(s_new - m)
     l = p_old.sum(axis=-1, keepdims=True) + p_new
-    pv = "bhgqk,bkhd->bqhgd"
-    out = jnp.einsum(pv, (p_old / l).astype(x.dtype),
-                     cache["v"].astype(x.dtype),
-                     preferred_element_type=jnp.float32) \
-        + jnp.einsum(pv, p_new / l, v_new.astype(jnp.float32))
+    out = _row_values((p_old / l).astype(x.dtype),
+                      cache["v"].astype(x.dtype)) \
+        + jnp.einsum("bhgqk,bkhd->bhgqd", p_new / l,
+                     v_new.astype(jnp.float32))
     out = out.astype(x.dtype)
     y = dense(p["wo"], out.reshape(b, 1, cfg.n_heads * cfg.head_dim))
-    return y, {"k": k_new[:, 0], "v": v_new[:, 0]}
+    return y, {"k": k_new.reshape(b, -1), "v": v_new.reshape(b, -1)}
 
 
 def attn_decode(cfg: Any, p: PyTree, x: jax.Array, cache: PyTree,
                 length: jax.Array) -> Tuple[jax.Array, PyTree]:
-    """One decode step.  x [B,1,D]; cache k/v [B,Smax,Hkv,hd]; length []
+    """One decode step.  x [B,1,D]; cache k/v [B,Smax,Hkv·hd]; length []
     (tokens already in cache).  Returns (y [B,1,D], new_cache).  A
     length per row [B] returns only the new row instead
     (``_attn_decode_rows``)."""
@@ -614,16 +652,19 @@ def attn_decode(cfg: Any, p: PyTree, x: jax.Array, cache: PyTree,
                                              length)
         y = dense(p["wo"], out.reshape(b, 1, cfg.n_heads * cfg.head_dim))
         return y, new_cache
-    k = lax.dynamic_update_slice(cache["k"], k_new.astype(cache["k"].dtype),
-                                 (0, length, 0, 0))
-    v = lax.dynamic_update_slice(cache["v"], v_new.astype(cache["v"].dtype),
-                                 (0, length, 0, 0))
+    k = lax.dynamic_update_slice(
+        cache["k"], k_new.reshape(b, 1, -1).astype(cache["k"].dtype),
+        (0, length, 0))
+    v = lax.dynamic_update_slice(
+        cache["v"], v_new.reshape(b, 1, -1).astype(cache["v"].dtype),
+        (0, length, 0))
     smax = k.shape[1]
     k_pos = jnp.arange(smax, dtype=jnp.int32)
     k_valid = k_pos <= length
     scale = 1.0 / math.sqrt(cfg.head_dim)
-    out = attention_full(q, k.astype(x.dtype), v.astype(x.dtype),
-                         scale=scale, causal=False, window=cfg.sliding_window,
-                         q_pos=positions, k_pos=k_pos, k_valid=k_valid)
+    s = _row_scores(q, k.astype(x.dtype), cfg.n_kv_heads) * scale \
+        + _mask_bias(positions, k_pos, False, cfg.sliding_window, k_valid)
+    p_ = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+    out = _row_values(p_, v.astype(x.dtype)).astype(x.dtype)
     y = dense(p["wo"], out.reshape(b, 1, cfg.n_heads * cfg.head_dim))
     return y, {"k": k, "v": v}

@@ -181,12 +181,12 @@ def _attn_fill_cache(cfg: Any, p: PyTree, h: jax.Array,
     from .common import rope_cos_sin, apply_rope
     cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
     k = apply_rope(k, cos, sin)
-    s = h.shape[1]
+    rows = h.shape[:2] + (-1,)
     return {
         "k": lax.dynamic_update_slice(
-            cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0)),
+            cache["k"], k.reshape(rows).astype(cache["k"].dtype), (0, 0, 0)),
         "v": lax.dynamic_update_slice(
-            cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0)),
+            cache["v"], v.reshape(rows).astype(cache["v"].dtype), (0, 0, 0)),
     }
 
 
@@ -449,7 +449,8 @@ def prefill(cfg: Any, params: PyTree, tokens: jax.Array, caches: PyTree, *,
 
 def decode_step(cfg: Any, params: PyTree, tokens: jax.Array, caches: PyTree,
                 length: jax.Array, *,
-                kernels: Optional[Dict[str, Any]] = None
+                kernels: Optional[Dict[str, Any]] = None,
+                row_writes: Optional[Dict[str, int]] = None
                 ) -> Tuple[jax.Array, PyTree]:
     """One token for every sequence.  tokens [B, 1]; length [] = the
     cache fill of every row, or [B] = each row's own fill.  Returns
@@ -460,7 +461,10 @@ def decode_step(cfg: Any, params: PyTree, tokens: jax.Array, caches: PyTree,
     read-only inside the scan: each attention layer attends its old
     rows plus its own new row and returns only that row, and after the
     scan one pass over the rows writes every layer's row at ``[layer, b,
-    length[b]]`` (in place when the caller donates the cache)."""
+    length[b]]`` (in place when the caller donates the cache), with the
+    ``kv_row_write`` kernel of ``kernels`` where a leaf fits it; while
+    tracing, ``row_writes`` (if given) counts the leaves written by
+    ``"kernel"`` and by ``"block"``."""
     x = _embed_in(cfg, params, tokens, None)
     per_row = jnp.ndim(length) == 1
     positions = length[:, None] if per_row \
@@ -469,26 +473,49 @@ def decode_step(cfg: Any, params: PyTree, tokens: jax.Array, caches: PyTree,
                                     mode="decode", caches=caches,
                                     length=length, kernels=kernels)
     if per_row:
-        new_caches = _write_rows(cfg, caches, new_caches, length)
+        with jax.named_scope("kv_row_write"):
+            new_caches = _write_rows(cfg, caches, new_caches, length,
+                                     (kernels or {}).get("kv_row_write"),
+                                     row_writes)
     return _head_out(cfg, params, x), new_caches
 
 
-# On a TPU the KV pool's sequence axis is its lane (minor) axis; a row
-# written at a dynamic position there makes XLA relayout the whole pool
-# around the write, a read-modify-write of the aligned block of positions
-# that holds it does not.
+# A leaf the kernel does not fit gets its row by a read-modify-write of
+# the aligned block of positions that holds it: where XLA makes a TPU
+# pool's sequence axis its lane (minor) axis, a row written at a dynamic
+# position makes it relayout the whole pool around the write.
 ROW_BLOCK = 128
 
 
-def _write_rows(cfg: Any, caches: PyTree, new: PyTree,
-                length: jax.Array) -> PyTree:
+def _fits_row_kernel(buf: jax.Array, axis: int) -> bool:
+    """A leaf [..., B, S, W] (batch at ``axis``) whose rows are whole
+    lanes and whose positions are whole tiles of rows of its dtype, as
+    the ``kv_row_write`` kernel takes them."""
+    tile = 32 // buf.dtype.itemsize       # kv_row_write.row_tile
+    return (buf.ndim == axis + 3 and buf.shape[-1] % 128 == 0
+            and buf.shape[-2] % tile == 0)
+
+
+def _write_rows(cfg: Any, caches: PyTree, new: PyTree, length: jax.Array,
+                kernel: Any = None,
+                counts: Optional[Dict[str, int]] = None) -> PyTree:
     """The caches after a per-row decode: each attention leaf gets its
     new row ``new`` (the leaf less its sequence axis) written at
-    ``[..., b, length[b]]``, slot by slot in place; a recurrent state
-    (same shape as its leaf, no sequence axis) replaces the old one."""
+    ``[..., b, length[b]]``, in place: by ``kernel`` (pool [L,B,S,W],
+    rows [L,B,W], lengths [B]) where the leaf fits it, else slot by slot
+    in aligned blocks; a recurrent state (same shape as its leaf, no
+    sequence axis) replaces the old one."""
+    counts = {} if counts is None else counts
+
     def put(buf, row, axis):
         if row.ndim == buf.ndim:
             return row
+        if kernel is not None and _fits_row_kernel(buf, axis):
+            counts["kernel"] = counts.get("kernel", 0) + 1
+            if axis == 1:
+                return kernel(buf, row, length)
+            return kernel(buf[None], row[None], length)[0]
+        counts["block"] = counts.get("block", 0) + 1
         blk = math.gcd(buf.shape[axis + 1], ROW_BLOCK)
         shape = buf.shape[:axis] + (1, blk) + buf.shape[axis + 2:]
         rest = (1,) * (row.ndim - axis - 1)

@@ -72,16 +72,23 @@ def sample_token(logits: jax.Array, temperature: float,
                                   axis=-1).astype(jnp.int32)
 
 
-def make_decode_fn(cfg: Any, kernels: Optional[Dict[str, Any]] = None):
+def make_decode_fn(cfg: Any, kernels: Optional[Dict[str, Any]] = None,
+                   stats: Optional[Dict[str, Any]] = None):
     """Decode of every slot as one batch: tokens [B,1], lengths [B],
     each slot at its own fill (the model's per-row-length path).  The
     inner function's name names the program in a profiler trace
-    (``jit_decode_slots``)."""
+    (``jit_decode_slots``).  Each trace sets ``stats["kv_row_write"]``
+    (if ``stats`` is given) to the cache leaves whose new rows it writes
+    by the ``kv_row_write`` kernel and by the block write."""
 
     def decode_slots(params: PyTree, tokens: jax.Array, caches: PyTree,
                      lengths: jax.Array) -> Tuple[jax.Array, PyTree]:
-        return decode_step(cfg, params, tokens, caches, lengths,
-                           kernels=kernels)
+        writes = {"kernel": 0, "block": 0}
+        out = decode_step(cfg, params, tokens, caches, lengths,
+                          kernels=kernels, row_writes=writes)
+        if stats is not None:
+            stats["kv_row_write"] = writes
+        return out
 
     return decode_slots
 
@@ -135,13 +142,13 @@ class ServingEngine:
         self.finished: List[Request] = []
         self.failed: List[Request] = []
         self._key = jax.random.PRNGKey(scfg.seed)
+        self.stats: Dict[str, Any] = {"ticks": 0, "prefills": 0,
+                                      "decoded_tokens": 0, "evictions": 0}
         # the cache is donated: the decode writes its new rows in place,
         # and nothing else holds ``self.caches`` across the call
-        self._decode = jax.jit(make_decode_fn(cfg, kernels),
+        self._decode = jax.jit(make_decode_fn(cfg, kernels, self.stats),
                                donate_argnums=(2,))
         self._prefill_cache: Dict[int, Any] = {}
-        self.stats = {"ticks": 0, "prefills": 0, "decoded_tokens": 0,
-                      "evictions": 0}
 
     # -- request intake ------------------------------------------------------
     def submit(self, req: Request) -> None:

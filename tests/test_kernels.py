@@ -1,5 +1,7 @@
 """Pallas kernels vs ref.py oracles — shape/dtype sweeps (interpret
 mode on CPU)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -121,3 +123,95 @@ def test_pallas_backend_refuses_cpu():
         ops.flash_attention(q, q, q, backend="pallas")
     with pytest.raises(RuntimeError, match="backend='interpret'"):
         ops.model_kernels(None, backend="pallas")
+
+
+# (W, dtype): rows of one and of two lane widths; R = 16 rows a tile for
+# bfloat16, 8 for float32
+ROW_WRITE_CASES = [(128, jnp.bfloat16), (256, jnp.bfloat16),
+                   (128, jnp.float32), (256, jnp.float32)]
+
+
+def _row_write_case(w, dtype, seed=0):
+    """A pool [L=3, B=6, S=256, W] of stale rows, one new row per slot,
+    and lengths: a free slot (0), R-1 and R on both sides of a tile
+    edge, 127 and 128 on both sides of a 128-position block, and
+    max_seq - 1."""
+    from repro.kernels.kv_row_write import row_tile
+    r, s = row_tile(dtype), 256
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    pool = jax.random.normal(ks[0], (3, 6, s, w), dtype)
+    rows = jax.random.normal(ks[1], (3, 6, w), dtype)
+    lengths = jnp.array([0, r - 1, r, 127, 128, s - 1], jnp.int32)
+    return pool, rows, lengths
+
+
+@pytest.mark.parametrize("case", ROW_WRITE_CASES)
+def test_kv_row_write_kernel_matches_block_write(case):
+    """Bit for bit what the model's block write gives, on a stacked leaf
+    [L, B, S, W] and on a prefix leaf [B, S, W], and what the oracle
+    gives."""
+    from repro.models.model import _write_rows
+    pool, rows, lengths = _row_write_case(*case)
+    hook = functools.partial(ops.kv_row_write, backend="interpret")
+    caches = {"prefix_0": {"k": pool[0]}, "stack": {"l0": {"k": pool}}}
+    new = {"prefix_0": {"k": rows[0]}, "stack": {"l0": {"k": rows}}}
+    counts = {}
+    got = _write_rows(None, caches, new, lengths, hook, counts)
+    want = _write_rows(None, caches, new, lengths)
+    assert counts == {"kernel": 2}
+    jax.tree.map(np.testing.assert_array_equal, got, want)
+    np.testing.assert_array_equal(
+        ops.kv_row_write(pool, rows, lengths, backend="interpret"),
+        kref.kv_row_write_ref(pool, rows, lengths))
+    # every other row is the pool's own
+    hit = np.zeros(pool.shape[1:3], bool)
+    hit[np.arange(6), np.asarray(lengths)] = True
+    np.testing.assert_array_equal(np.asarray(got["stack"]["l0"]["k"])
+                                  [:, ~hit], np.asarray(pool)[:, ~hit])
+
+
+def test_kv_row_write_aliases_the_pool_to_its_output():
+    pool, rows, lengths = _row_write_case(128, jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(functools.partial(
+        ops.kv_row_write, backend="interpret"))(pool, rows, lengths)
+
+    def calls(jx):
+        for e in jx.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e
+            for v in e.params.values():
+                sub = getattr(v, "jaxpr", v)
+                if hasattr(sub, "eqns"):
+                    yield from calls(sub)
+
+    (call,) = calls(jaxpr.jaxpr)
+    # operands: lengths (scalar prefetch), rows, pool -> output 0
+    assert call.params["input_output_aliases"] == ((2, 0),)
+
+
+def test_kv_row_write_engages_only_where_it_fits():
+    """The model hands the kernel a leaf whose rows are whole lanes and
+    whose positions are whole tiles of rows; any other leaf keeps the
+    block write, and the kernel itself refuses it."""
+    from repro.models.model import _write_rows
+    hook = functools.partial(ops.kv_row_write, backend="interpret")
+    lengths = jnp.array([0, 5], jnp.int32)
+    leaves = {"lanes": (3, 2, 32, 64), "rows": (3, 2, 24, 128),
+              "fits": (3, 2, 32, 128)}
+    caches = {"stack": {k: jnp.zeros(v, jnp.bfloat16)
+                        for k, v in leaves.items()}}
+    new = {"stack": {k: jnp.ones(v[:2] + v[3:], jnp.bfloat16)
+                     for k, v in leaves.items()}}
+    counts = {}
+    got = _write_rows(None, caches, new, lengths, hook, counts)
+    assert counts == {"kernel": 1, "block": 2}
+    jax.tree.map(np.testing.assert_array_equal, got,
+                 _write_rows(None, caches, new, lengths))
+    with pytest.raises(ValueError, match="S of 16"):
+        hook(caches["stack"]["rows"], new["stack"]["rows"], lengths)
+
+
+def test_kv_row_write_pallas_refuses_cpu():
+    pool, rows, lengths = _row_write_case(128, jnp.bfloat16)
+    with pytest.raises(RuntimeError, match="backend='interpret'"):
+        ops.kv_row_write(pool, rows, lengths, backend="pallas")

@@ -84,24 +84,36 @@ def test_hybrid_serving_greedy():
 
 
 def _decode_families():
+    import dataclasses
     from repro.configs.base import get_smoke_config
+    dense = ModelConfig(name="d", n_layers=2, d_model=64, n_heads=4,
+                        n_kv_heads=2, d_ff=128, vocab=211, **F32)
+    window = get_smoke_config("starcoder2-7b")
     return {
-        "dense": ModelConfig(name="d", n_layers=2, d_model=64, n_heads=4,
-                             n_kv_heads=2, d_ff=128, vocab=211, **F32),
-        "sliding_window": get_smoke_config("starcoder2-7b"),
+        "dense": dense,
+        "sliding_window": window,
         "mla": get_smoke_config("deepseek-v3-671b"),
         "hybrid": ModelConfig(**HYBRID),
+        # heads of 64: a row of 2 kv heads is 128 wide, whole lanes, so
+        # the kv_row_write kernel takes the attention leaves
+        "dense+kv_row_write": dataclasses.replace(dense, head_dim=64),
+        "sliding_window+kv_row_write": dataclasses.replace(window,
+                                                           head_dim=64),
     }
 
 
 @pytest.mark.parametrize("family", ["dense", "sliding_window", "mla",
-                                    "hybrid"])
+                                    "hybrid", "dense+kv_row_write",
+                                    "sliding_window+kv_row_write"])
 def test_decode_slots_matches_per_slot_decode(family):
     """The engine's one-batch decode with a length per slot gives every
     slot the logits and cache that ``decode_step`` with that slot's
     scalar length gives it alone: slots at 0, at max_seq - 2 and on
     both sides of a block edge of the row write, and a free slot (length
-    0, stale cache)."""
+    0, stale cache).  The ``+kv_row_write`` cases give the engine the
+    row-write kernel (interpret mode), which takes every attention
+    leaf."""
+    from repro.kernels import ops
     from repro.models import decode_step
     from repro.models.model import ROW_BLOCK, cache_batch_axes
     cfg = _decode_families()[family]
@@ -109,9 +121,13 @@ def test_decode_slots_matches_per_slot_decode(family):
     max_seq = 2 * ROW_BLOCK
     lengths = np.array([0, max_seq - 2, ROW_BLOCK - 1, ROW_BLOCK, 0],
                        np.int32)
+    kernels = None
+    if family.endswith("+kv_row_write"):
+        kernels = {"kv_row_write": lambda pool, rows, n: ops.kv_row_write(
+            pool, rows, n, backend="interpret")}
     eng = ServingEngine(cfg, params, ServeConfig(n_slots=len(lengths),
                                                  max_seq=max_seq),
-                        use_executor=False)
+                        kernels=kernels, use_executor=False)
     leaves, tree = jax.tree.flatten(eng.caches)
     keys = jax.random.split(jax.random.PRNGKey(3), len(leaves))
     caches = tree.unflatten([jax.random.normal(k, x.shape, x.dtype)
@@ -126,6 +142,8 @@ def test_decode_slots_matches_per_slot_decode(family):
         want.append(decode_step(cfg, params, tokens[b:b + 1], one,
                                 jnp.int32(n)))
     lg, got = eng._decode(params, tokens, caches, jnp.asarray(lengths))
+    if kernels is not None:
+        assert eng.stats["kv_row_write"] == {"kernel": 2, "block": 0}
     for b, (lg_b, cache_b) in enumerate(want):
         np.testing.assert_allclose(lg[b], lg_b[0], rtol=1e-5, atol=1e-5)
         jax.tree.map(lambda g, w, a: np.testing.assert_allclose(
@@ -147,6 +165,9 @@ def test_decode_slots_donates_and_moves_no_cache_sized_layout():
     tokens = jnp.zeros((4, 1), jnp.int32)
     lengths = jnp.zeros((4,), jnp.int32)
     text = eng._decode.lower(params, tokens, eng.caches, lengths).as_text()
+    # the pool leaf: [layers, slots, positions, n_kv_heads * head_dim]
+    assert [x.shape for x in jax.tree.leaves(eng.caches)] == \
+        [(2, 4, 64, cfg.n_kv_heads * cfg.head_dim)] * 2
     n_cache = len(jax.tree.leaves(eng.caches))
     assert text.count("tf.aliasing_output") \
         + text.count("jax.buffer_donor") == n_cache
@@ -337,3 +358,47 @@ def test_span_counts_match_the_engine_counters(traced):
     last = max(_named(spans, "amt.run"), key=lambda r: r[1])
     assert last[3]["tasks"] == len(eng._executor.graph)
 
+
+
+def test_decode_split_classes_the_row_write_and_the_pool_read():
+    """``benchmarks/decode_split.py`` splits a step's device time by the
+    named scopes of the compiled decode: the row write (the kernel, or
+    the block write) and the attention's read of the pool."""
+    import importlib.util
+    import os
+    from repro.kernels import ops
+    from repro.models import init_cache
+    from repro.serving.engine import make_decode_fn
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                        "decode_split.py")
+    spec = importlib.util.spec_from_file_location("decode_split", path)
+    ds = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ds)
+    cfg = _decode_families()["dense+kv_row_write"]
+    params = make(cfg)
+    caches = init_cache(cfg, 4, 64)
+    args = (params, jnp.zeros((4, 1), jnp.int32), caches,
+            jnp.zeros((4,), jnp.int32))
+    kernels = {"kv_row_write": lambda pool, rows, n: ops.kv_row_write(
+        pool, rows, n, backend="interpret")}
+    for ks in (kernels, None):
+        hlo = jax.jit(make_decode_fn(cfg, ks)).lower(*args).compile() \
+            .as_text()
+        classes = ds.scope_of_ops(hlo)
+        assert {"row_write", "kv_read", "rest"} <= set(classes.values())
+        assert not any(ds.re.search(rf"%{n} = .* while\(", hlo)
+                       for n in classes)
+        events = [(f"%{n} = fusion", 1.0) for n in classes] + \
+            [("while.1", 5.0)]
+        got = ds.split(events, classes)
+        assert sum(got.values()) == len(classes)
+        assert got["row_write"] == list(classes.values()).count(
+            "row_write")
+    # a TPU program's layouts put upper-case tiles before the opcode
+    tpu = ('%while.1 = (s32[]{:T(128)}, bf16[24,8,256,128]{3,2,1,0:T(8,128)'
+           '(2,1)}) while(%tuple.3), condition=%cond.1, body=%body.1\n'
+           '%kv_row_write.2 = bf16[24,8,256,128]{3,2,1,0:T(8,128)(2,1)S(1)} '
+           'custom-call(%a, %b, %c), custom_call_target="tpu_custom_call", '
+           'metadata={op_name="jit(decode_slots)/kv_row_write/'
+           'jit(kv_row_write)/pallas_call"}')
+    assert ds.scope_of_ops(tpu) == {"kv_row_write.2": "row_write"}

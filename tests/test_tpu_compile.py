@@ -79,3 +79,47 @@ def test_moe_gmm_compiles_at_qwen3_moe_expert_share(one_chip):
     w = _spec((e, d, f), jnp.bfloat16, one_chip)
     hlo = _compiled_hlo(moe_gmm, xb, w)
     assert "tpu_custom_call" in hlo
+
+
+def test_decode_slots_writes_rows_in_place_at_qwen2_0_5b_chat(one_chip,
+                                                               monkeypatch):
+    """The serving engine's decode at chat's sizes (qwen2-0.5b, 64 slots
+    × 2048 positions, the pool donated) with the kernels a TPU gets
+    holds the row-write kernel and lays no part of the pool out anew:
+    no copy or transpose as large as one layer's slice of a leaf (a
+    relayout reads 1.6 GB of temporaries; the block write 387,072 B)."""
+    import re
+
+    import numpy as np
+
+    from repro.configs.base import get_config
+    from repro.kernels import ops
+    from repro.models import init_cache
+    from repro.models.model import abstract_init
+    from repro.serving import ServeConfig, ServingEngine
+    # the described chip is not the backend JAX runs on: ask for the
+    # compiled kernels as the engine gets them on a TPU
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = get_config("qwen2-0.5b")
+    b, s = 64, 2048
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _spec(x.shape, x.dtype, one_chip),
+                            tree)
+
+    params = on_chip(abstract_init(cfg)[0])
+    caches = on_chip(jax.eval_shape(lambda: init_cache(cfg, b, s)))
+    eng = ServingEngine(cfg, params, ServeConfig(n_slots=1, max_seq=16),
+                        kernels=ops.model_kernels(cfg), use_executor=False)
+    compiled = eng._decode.lower(
+        params, _spec((b, 1), jnp.int32, one_chip), caches,
+        _spec((b,), jnp.int32, one_chip)).compile()
+    hlo = compiled.as_text()
+    assert eng.stats["kv_row_write"] == {"kernel": 2, "block": 0}
+    assert "tpu_custom_call" in hlo
+    layer = b * s * cfg.n_kv_heads * cfg.head_dim
+    moved = [m.group(0) for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\S* (?:copy|transpose)\(", hlo)
+        if np.prod([int(d) for d in m.group(1).split(",")]) >= layer]
+    assert moved == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
